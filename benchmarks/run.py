@@ -252,7 +252,8 @@ def bench_mvstore():
 
 
 # ---------------------------------------------------------------------------
-# Kernel microbenches (interpret mode — correctness-path timing only)
+# Kernel microbenches (the platform's path: the compiled kernel on TPU,
+# the jnp reference elsewhere — a CPU timing times the reference)
 # ---------------------------------------------------------------------------
 
 
@@ -278,13 +279,15 @@ def bench_kernels():
 
     t = timeit(lambda: ops.flash_attention(q, k, v, causal=True,
                                            block_q=64, block_k=64))
-    _emit("kernels/flash_attention_interp", t * 1e6, f"S={S};H={H};D={D}")
+    plat = jax.default_backend()
+    _emit("kernels/flash_attention", t * 1e6,
+          f"S={S};H={H};D={D};platform={plat}")
     rows.append({"kernel": "flash_attention", "seconds": t})
 
     ring = jax.random.normal(key, (4, 1024, 64), jnp.float32)
     ts = jnp.asarray([1, 5, 3, -1], jnp.int32)
     t = timeit(lambda: ops.snapshot_select(ring, ts, jnp.int32(4)))
-    _emit("kernels/snapshot_select_interp", t * 1e6, "R=4;n=64k")
+    _emit("kernels/snapshot_select", t * 1e6, f"R=4;n=64k;platform={plat}")
     rows.append({"kernel": "snapshot_select", "seconds": t})
     _save("kernels", rows)
     return rows
@@ -441,6 +444,8 @@ BENCHES = {
 
 def main() -> None:
     global SEED
+    from repro.runtime.compile_cache import use_compile_cache
+    use_compile_cache()
     argv = sys.argv[1:]
     if "--seed" in argv:
         i = argv.index("--seed")

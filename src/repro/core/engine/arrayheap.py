@@ -50,7 +50,23 @@ def unpack_lock(word: int) -> LockState:
                      ((word >> 2) & _TID_MASK) - _TID_BIAS, bool(word & 1))
 
 
+def unpack_words(words) -> Tuple[np.ndarray, np.ndarray, np.ndarray]:
+    """Packed lock words -> ``(version int64, owner int32, meta int32)``
+    with meta bit0 = locked, bit1 = flag — the layout the bulk
+    validators (numpy and the Pallas kernels) consume."""
+    w = np.asarray(words, np.int64)
+    ver = w >> _VER_SHIFT
+    own = (((w >> 2) & _TID_MASK) - _TID_BIAS).astype(np.int32)
+    meta = (((w >> 1) & 1) | ((w & 1) << 1)).astype(np.int32)
+    return ver, own, meta
+
+
 _UNLOCKED_WORD = pack_lock(LockState(False, 0, -1, False))
+
+
+def _fits32(v: int) -> bool:
+    """The int32 range the device path admits (``ops.beyond_int32``)."""
+    return -(1 << 31) < v < (1 << 31)
 
 
 def check_addr_bounds(idx: np.ndarray, n: int) -> None:
@@ -117,9 +133,13 @@ class ArrayHeap:
         self._buf = np.zeros(max(capacity, 1), np.int64)
         self._len = 0
         self._lock = threading.Lock()
+        #: sticky: some stored word left the int32 range (see fits_int32)
+        self._wide = False
 
     def alloc(self, n: int, init: Any = None) -> int:
         fill = 0 if init is None else int(init)
+        if not _fits32(fill):
+            self._wide = True
         with self._lock:
             base = self._len
             need = base + n
@@ -148,8 +168,11 @@ class ArrayHeap:
         # buffer, and a write that raced the copy would land in the
         # discarded old array and silently vanish (ObjectHeap never
         # rebinds its list, so only the array heap has this hazard)
+        value = int(value)
+        if not _fits32(value):
+            self._wide = True
         with self._lock:
-            self._buf[addr] = int(value)
+            self._buf[addr] = value
 
     def __len__(self) -> int:
         return self._len
@@ -183,12 +206,30 @@ class ArrayHeap:
         if vals.dtype.kind not in "iu":       # match scalar int(value)
             vals = np.fromiter((int(v) for v in values), np.int64,
                                idx.size)
+        if vals.size and not (_fits32(int(vals.min()))
+                              and _fits32(int(vals.max()))):
+            self._wide = True
         with self._lock:
             check_addr_bounds(idx, self._len)
             self._buf[idx] = vals
 
+    @property
+    def fits_int32(self) -> bool:
+        """False once a word beyond the int32 range was stored.  The
+        device copy is int32 (the repo never enables jax x64), so such a
+        heap must take the numpy twins instead of the kernels.  Sticky:
+        overwriting the word later does not clear it."""
+        return not self._wide
+
     def jnp(self):
+        """The live words as a jax int32 array, for the device kernels.
+        Raises OverflowError when ``fits_int32`` is False rather than
+        truncating a word."""
         import jax.numpy as jnp
+        if self._wide:
+            raise OverflowError(
+                "heap holds a word beyond int32; its device copy would "
+                "truncate it")
         return jnp.asarray(self._buf[:self._len])
 
 
@@ -260,15 +301,9 @@ class ArrayLockTable(LockTable):
                                                 np.ndarray]:
         """One consistent snapshot of many lock words.
 
-        Returns ``(version int64[N], owner int32[N], meta int32[N])`` with
-        meta bit0 = locked, bit1 = flag — the layout the bulk validators
-        (numpy and the Pallas kernel) consume.
+        Returns ``unpack_words`` of the words at ``idxs``.
         """
-        w = self._words[idxs]                       # single fancy-index copy
-        ver = w >> _VER_SHIFT
-        own = (((w >> 2) & _TID_MASK) - _TID_BIAS).astype(np.int32)
-        meta = (((w >> 1) & 1) | ((w & 1) << 1)).astype(np.int32)
-        return ver, own, meta
+        return unpack_words(self._words[idxs])      # single fancy-index copy
 
     def held_by(self, tid: int) -> np.ndarray:
         """Indices currently write-locked by ``tid`` (exhaustion cleanup)."""

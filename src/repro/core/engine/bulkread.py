@@ -6,7 +6,7 @@ than the TM.  This module is the engine-level batch: ONE heap gather
 bracketed by TWO consistent lock-word gathers, then a vectorized
 stability predicate — so a long read snapshots its whole batch in a
 handful of array ops (numpy on CPU, the ``kernels/gather_read.py`` /
-``kernels/validate.py`` Pallas launches on TPU via ``KERNEL_INTERPRET=0``).
+``kernels/validate.py`` Pallas launches on TPU — ``kernels.ops.on_tpu``).
 
 Soundness argument, per element ``i``:
 
@@ -35,7 +35,7 @@ version lists, and the packed VLT mirror (``core/vlt.py`` —
 per-lock-index int64 rows of the newest committed ``(timestamp, data)``
 pairs, seqlock-bracketed) resolves them in ONE ``PackedVLT.select``
 gather — ``np_version_select`` on CPU, the
-``kernels/version_select.py`` Pallas kernel when ``KERNEL_INTERPRET=0``
+``kernels/version_select.py`` Pallas kernel on TPU
 — so the Mode-U/Q hybrid bulk read (``MultiversePolicy.read_bulk`` →
 ``_bulk_versioned_gather``) only falls through to the per-word
 version-list traversal for what the mirror cannot represent (colliding
@@ -86,16 +86,16 @@ def gather_row(row, addrs: np.ndarray) -> np.ndarray:
     """``row[addrs]`` with the kernel dispatch, for any 1-D value row.
 
     Fancy-index on CPU; one ``ops.snapshot_read`` (gather_read kernel)
-    launch when ``KERNEL_INTERPRET=0``.  The single home of the bounds
-    contract on the kernel path: numpy raises on an out-of-range address
-    while ``jnp.take`` would CLAMP it to the last word, so the guard
-    keeps both paths raising identically.  Serves the word-level array
-    heap AND the MVStore live-block / ring-row gathers.
+    launch on TPU.  The single home of the bounds contract on the kernel
+    path: numpy raises on an out-of-range address while a device gather
+    would read whatever row the DMA addresses, so the guard keeps both
+    paths raising identically.  Serves the word-level array heap AND the
+    MVStore live-block / ring-row gathers.
     """
     from repro.kernels import ops
-    if not ops.INTERPRET:
-        if addrs.size and int(addrs.max(initial=0)) >= row.shape[0]:
-            raise IndexError(int(addrs.max()))
+    if ops.on_tpu():
+        from repro.core.engine.arrayheap import check_addr_bounds
+        check_addr_bounds(addrs, row.shape[0])
         return np.asarray(ops.snapshot_read(row, addrs))
     if isinstance(row, np.ndarray):
         return row[addrs]
@@ -115,17 +115,21 @@ def heap_gather(heap, addrs: np.ndarray):
     """``heap[addrs]`` in one pass.
 
     ``ArrayHeap`` answers with a single fancy-index (one ``gather_row``
-    kernel launch over ``heap.jnp()`` on TPU); ``ObjectHeap`` with one
-    list pass; anything else falls back to scalar indexing.  Returns
-    ndarray (array heaps) or list (object heaps).
+    kernel launch over ``heap.jnp()`` on TPU — unless the heap holds a
+    word beyond int32, which the device copy would truncate: that heap
+    takes the numpy twin, counted in ``ops.COUNTS``); ``ObjectHeap``
+    with one list pass; anything else falls back to scalar indexing.
+    Returns ndarray (array heaps) or list (object heaps).
     """
     g = getattr(heap, "gather", None)
     if g is None:
         return [heap[int(a)] for a in addrs]
     if getattr(heap, "jnp", None) is not None:
         from repro.kernels import ops
-        if not ops.INTERPRET:      # real TPU: one gather_read launch
-            return gather_row(heap.jnp(), addrs)
+        if ops.on_tpu():
+            if heap.fits_int32:
+                return gather_row(heap.jnp(), addrs)
+            ops.COUNTS.twin("gather_read")
     return g(addrs)
 
 

@@ -254,7 +254,7 @@ def scatter_row(row, addrs, values):
 
     The write-back analogue of ``bulkread.gather_row`` for immutable
     (jax) rows: one DONATED ``ops.publish_row`` call — a
-    ``scatter_write`` launch when ``KERNEL_INTERPRET=0``, the jitted
+    ``scatter_write`` launch on TPU, the jitted
     jnp scatter otherwise — so the row never round-trips through the
     host (``write_back`` returns an ndarray, a device->host heap copy
     per commit, which the device path must not pay).  The caller hands

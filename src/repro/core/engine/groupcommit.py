@@ -25,7 +25,7 @@ tick and one publish sweep instead of N of each:
     member's writes, one release sweep stamping the shared version.
     On CPU the in-file numpy twin (``np_commit_decide``) is the
     production verdict and the scatter goes through the in-place heap
-    (the ``heap_scatter`` contract); with ``KERNEL_INTERPRET=0`` the
+    (the ``heap_scatter`` contract); on TPU the
     whole publish is one ``ops.commit_fused`` megakernel launch over
     the device-resident row;
   * anything it cannot prove safe — colliding footprints, encounter
@@ -338,9 +338,8 @@ class CommitBatcher:
         r_flat, r_seg, _ = pack_segments([p[4] for p in gp])
         tids = np.fromiter((d.tid for d in group), np.int64, len(group))
 
-        from repro.core.engine.arrayheap import (_TID_BIAS, _TID_MASK,
-                                                 _UNLOCKED_WORD,
-                                                 _VER_SHIFT)
+        from repro.core.engine.arrayheap import (_UNLOCKED_WORD,
+                                                 _VER_SHIFT, unpack_words)
 
         # durable group commit: ONE buffered append carries every
         # member's PREPARE frame, landed BEFORE the claim window (the
@@ -375,21 +374,13 @@ class CommitBatcher:
                 ok = np.ones(len(group), bool)
                 all_ok = any_ok = True
             else:
-                def fields(words):
-                    ver = words >> _VER_SHIFT
-                    own = (((words >> 2) & _TID_MASK)
-                           - _TID_BIAS).astype(np.int32)
-                    meta = (((words >> 1) & 1)
-                            | ((words & 1) << 1)).astype(np.int32)
-                    return ver, own, meta
-
                 r_seen = (np.concatenate([p[5] for p in gp]) if gp
                           else np.zeros((0,), np.int64))
                 rcs = np.fromiter((d.r_clock for d in group),
                                   np.int64, len(group))
                 r_words = locks.words_at(r_flat)
-                lv, lo, lm = fields(l_words)
-                rv, ro, rm = fields(r_words)
+                lv, lo, lm = unpack_words(l_words)
+                rv, ro, rm = unpack_words(r_words)
                 ok = np_commit_decide(lv, lo, lm, l_seg, rv, ro, rm,
                                       r_seen, r_seg, tids, rcs,
                                       len(group), mode)
@@ -454,7 +445,7 @@ class CommitBatcher:
 
         CPU production: one in-place ``heap_scatter`` (the heap IS the
         numpy buffer — ``engine/commit.heap_scatter``'s contract).
-        ``KERNEL_INTERPRET=0``: the full ``ops.commit_fused`` megakernel
+        TPU: the full ``ops.commit_fused`` megakernel
         over the device row — validate + claim-check + scatter + stamp
         in one launch (the claim words read as locked-by-owner, so the
         in-kernel verdict reproduces ``ok`` exactly), then only the
@@ -468,7 +459,11 @@ class CommitBatcher:
                  else np.zeros((0,), np.int64))
         if not addrs.size:
             return
-        if not ops.INTERPRET and getattr(eng.heap, "jnp", None) is not None:
+        device = ops.on_tpu() and getattr(eng.heap, "jnp", None) is not None
+        if device and not eng.heap.fits_int32:
+            ops.COUNTS.twin("commit_fused")   # int64 words: numpy twin
+            device = False
+        if device:
             w_flat, w_seg, _ = pack_segments(w_addrs)
             vals = np.concatenate(
                 [np.asarray(v, np.int64) for v in w_vals])

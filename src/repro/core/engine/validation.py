@@ -15,11 +15,9 @@ consistent ``gather`` of the packed lock words, then a vectorized
 predicate — once the read set is large enough to amortize it.  The bulk
 predicate itself has two implementations sharing one contract:
 
-  * ``np_validate``   — numpy, the CPU fast path and interpret-mode oracle;
+  * ``np_validate``   — numpy, the CPU path and the kernel's test oracle;
   * ``kernels/validate.py`` — the Pallas kernel (one launch per read set),
-    used when ``KERNEL_INTERPRET=0`` (real TPU); in interpret mode the
-    per-tile Python interpreter would cost more than it saves, so the
-    numpy path serves as the documented CPU fallback.
+    used on TPU (``kernels.ops.on_tpu``).
 
 NOrec validates VALUES, not versions: ``validate_values`` re-reads each
 ``(addr, value)`` pair against the heap.
@@ -89,7 +87,7 @@ def revalidate_bulk(locks, read_set: List[tuple], r_clock: int, tid: int,
     seen = np.fromiter((e[1] for e in read_set), np.int64, len(read_set))
     ver, own, meta = gather(idxs)
     from repro.kernels import ops
-    if not ops.INTERPRET:
+    if ops.on_tpu():
         return bool(ops.validate_readset(ver, own, meta, seen, r_clock,
                                          tid, mode))
     return np_validate(ver, own, meta, seen, r_clock, tid, mode)

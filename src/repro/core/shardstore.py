@@ -80,14 +80,11 @@ def shard_devices(n_shards: int, mesh=None) -> List[Any]:
     ``jax.devices()`` — and a single-device host gets ``[None] * n``
     (placement is a no-op there, the sharding still buys per-shard
     clocks)."""
-    try:
-        import jax
-        if mesh is not None:
-            from repro.launch.sharding import shard_device_slices
-            return shard_device_slices(mesh, n_shards)
-        devs = jax.devices()
-    except Exception:                      # pragma: no cover - no backend
-        return [None] * n_shards
+    import jax
+    if mesh is not None:
+        from repro.launch.sharding import shard_device_slices
+        return shard_device_slices(mesh, n_shards)
+    devs = jax.devices()
     if len(devs) <= 1:
         return [None] * n_shards
     return [devs[s % len(devs)] for s in range(n_shards)]

@@ -233,7 +233,7 @@ class PackedVLT:
         the caller re-reads through the scalar traverse.  One seqlock-
         bracketed gather of the mirror rows, a vectorized way match,
         then one vectorized select over the matched ways (numpy twin on
-        CPU, the Pallas kernel when KERNEL_INTERPRET=0).
+        CPU, the Pallas kernel on TPU).
         """
         s1 = self._seq[idxs]
         rows_addr = self._addr[idxs]                   # [N, ways]
@@ -246,7 +246,7 @@ class PackedVLT:
         rows = np.arange(idxs.shape[0])
         ts_w, data_w = ts[rows, way], data[rows, way]  # [N, depth]
         from repro.kernels import ops
-        if not ops.INTERPRET:
+        if ops.on_tpu():
             vals, found = ops.version_select(ts_w, data_w, r_clock)
         else:
             vals, found = np_version_select(ts_w, data_w, r_clock)

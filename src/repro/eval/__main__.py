@@ -19,6 +19,7 @@ from repro.eval.driver import durability_headline, longread_headline, \
     reliability_headline, run_eval, rwmix_headline, serving_headline, \
     shardscale_headline, structrq_headline
 from repro.eval.workloads import WORKLOADS
+from repro.runtime.compile_cache import use_compile_cache
 
 
 def _fmt_row(row: dict) -> str:
@@ -89,6 +90,7 @@ def main(argv=None) -> int:
     ap.add_argument("--list", action="store_true",
                     help="list workloads and exit")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     if args.list:
         for name, w in sorted(WORKLOADS.items()):

@@ -12,18 +12,19 @@ conflict-disjoint transactions:
 in ONE launch over a segment-offset layout: ragged per-transaction
 read/write sets are packed into flat ``(addrs, values, txn_id)`` /
 ``(lock fields, txn_id)`` batches (``pack_segments`` below), and a
-per-transaction verdict is accumulated with a scatter-min into a
-constant-index ``ok`` block — a member is publishable iff EVERY one of
-its read entries validates and EVERY one of its write locks is free.
+per-transaction verdict is accumulated as a running minimum in an
+``ok`` vector — a member is publishable iff EVERY one of its read
+entries validates and EVERY one of its write locks is free.
 
-Layout mirrors the gather/scatter kernels: the heap rides in as one
-full block, the write batch is tiled over the grid, the (small)
-read/lock/txn vectors are full constant-index blocks.  Grid step 0
-computes the verdict, seeds the output heap and stamps the release
-versions; every step then scatters its write tile, with the addresses
-of FAILED members redirected to one-past-the-end (dropped by jax
-scatter semantics — the same ragged-padding trick ``ops.write_back``
-uses, so a failed member's writes never touch the heap).
+Layout mirrors the scatter kernel: the heap stays in HBM as
+``[H / 128, 128]`` rows aliased to the output, the write batch rides in
+SMEM tiles sorted by address, and the (small) read/lock/txn vectors are
+whole SMEM blocks.  Grid step 0 computes the verdict with scalar loops
+(a running per-member minimum — a member survives iff every entry it
+owns passes) and stamps the release versions; every step then scatters
+its write tile through ``scatter_write.scatter_rows``, skipping the
+entries of FAILED members, so a failed member's writes never touch the
+heap.
 
 The caller owns atomicity: on the CPU engine the covering lock stripes
 are held around the decision + claim (``groupcommit.py``); at the
@@ -45,7 +46,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.scatter_write import LANES, scatter_rows
 # fault injection only (stdlib-only module — keeps the kernels
 # engine-import-free): np_commit_fused splits its scatter around the
 # ``mid_scatter`` point so crash drills can freeze a partial-lane image
@@ -166,92 +169,118 @@ def np_commit_fused(heap, w_addr, w_val, w_seg,
     return out, ok, new_l_ver
 
 
-def _fused_kernel(mode, n_heap,
-                  heap_ref, wa_ref, wv_ref, ws_ref,
+def _fused_kernel(mode, n_words,
+                  wa_ref, wv_ref, ws_ref,
                   lv_ref, lo_ref, lm_ref, ls_ref,
                   rv_ref, ro_ref, rm_ref, rn_ref, rs_ref,
-                  tid_ref, rc_ref, cv_ref,
-                  o_heap, o_ok, o_lver):
-    # step 0: the whole verdict in one pass over the constant-index
-    # read/lock blocks (scatter-min accumulates per-member AND), then
-    # seed the heap and stamp the release versions
+                  tid_ref, rc_ref, cv_ref, heap_in,
+                  heap_hbm, o_ok, o_lver, row, sem):
+    del heap_in                      # aliased to heap_hbm
+
+    def bit(cond):
+        return jnp.where(cond, 1, 0)
+
+    # step 0: the whole verdict as scalar loops over the SMEM read/lock
+    # batches, then the release versions
     @pl.when(pl.program_id(0) == 0)
     def _decide():
-        tids = tid_ref[...]
-        rcs = rc_ref[...]
-        ok = jnp.ones(o_ok.shape, jnp.int32)
-        rm = rm_ref[...]
-        locked = (rm & 1) != 0
-        flagged = (rm & 2) != 0
-        seg = rs_ref[...]
-        mine = locked & (ro_ref[...] == tids[seg])
-        ver = rv_ref[...]
-        rc = rcs[seg]
-        if mode == MODE_LT:
-            valid = mine | ((~locked) & (~flagged) & (ver < rc))
-        elif mode == MODE_LE:
-            valid = ((~locked) | mine) & (ver <= rc)
-        else:
-            valid = ((~locked) | mine) & (ver == rn_ref[...])
-        ok = ok.at[seg].min(valid.astype(jnp.int32))
-        lm = lm_ref[...]
-        llocked = (lm & 1) != 0
-        lflag = (lm & 2) != 0
-        lseg = ls_ref[...]
-        lown = llocked & (lo_ref[...] == tids[lseg])
-        claim = jnp.logical_not((llocked | lflag) & (~lown))
-        ok = ok.at[lseg].min(claim.astype(jnp.int32))
-        o_ok[...] = ok
-        o_lver[...] = jnp.where(ok[lseg] == 1, cv_ref[0], lv_ref[...])
-        o_heap[...] = heap_ref[...]
+        def init(t, c):
+            o_ok[t] = 1
+            return c
+
+        jax.lax.fori_loop(0, o_ok.shape[0], init, 0)
+
+        def read_entry(e, c):
+            seg = rs_ref[e]
+            meta = rm_ref[e]
+            locked = meta & 1
+            flagged = (meta >> 1) & 1
+            mine = locked & bit(ro_ref[e] == tid_ref[seg])
+            ver = rv_ref[e]
+            if mode == MODE_LT:
+                valid = mine | ((1 - locked) & (1 - flagged)
+                                & bit(ver < rc_ref[seg]))
+            elif mode == MODE_LE:
+                valid = ((1 - locked) | mine) & bit(ver <= rc_ref[seg])
+            else:
+                valid = ((1 - locked) | mine) & bit(ver == rn_ref[e])
+            o_ok[seg] = jnp.minimum(o_ok[seg], valid)
+            return c
+
+        jax.lax.fori_loop(0, rs_ref.shape[0], read_entry, 0)
+
+        def lock_entry(e, c):
+            seg = ls_ref[e]
+            meta = lm_ref[e]
+            locked = meta & 1
+            held = (locked | ((meta >> 1) & 1)) \
+                & (1 - (locked & bit(lo_ref[e] == tid_ref[seg])))
+            o_ok[seg] = jnp.minimum(o_ok[seg], 1 - held)
+            return c
+
+        jax.lax.fori_loop(0, ls_ref.shape[0], lock_entry, 0)
+
+        def stamp(e, c):
+            o_lver[e] = jnp.where(o_ok[ls_ref[e]] == 1, cv_ref[0],
+                                  lv_ref[e])
+            return c
+
+        jax.lax.fori_loop(0, ls_ref.shape[0], stamp, 0)
 
     # every step (incl. 0, after the decide above): scatter this write
-    # tile — failed members' addresses redirect one past the end, which
-    # jax scatter drops, so their values never land
-    okv = o_ok[...][ws_ref[...]]
-    addr = jnp.where(okv == 1, wa_ref[...], n_heap)
-    o_heap[...] = o_heap[...].at[addr].set(wv_ref[...])
+    # tile; a failed member's writes are skipped, so they never land
+    scatter_rows(heap_hbm, row, sem, n_words, wa_ref, wv_ref,
+                 lambda i: o_ok[ws_ref[i]] == 1)
 
 
 def commit_fused_flat(heap, w_addr, w_val, w_seg,
                       l_ver, l_own, l_meta, l_seg,
                       r_ver, r_own, r_meta, r_seen, r_seg,
-                      tids, r_clocks, commit_ver, *, mode: int = MODE_LE,
-                      tile: int = 512, interpret: bool = True):
-    """heap: [H]; write batch [N] (N a multiple of ``tile``, int32 addrs
-    and segs, values heap.dtype); lock batch [L]; read batch [M]; txn
-    vectors [T] (int32); commit_ver: [1] int32 (REBASED — 0 by the
-    wrapper's convention).  Returns ``(heap' [H], ok [T] int32,
-    lver' [L] int32)``.  Pad rows must point their seg at a dummy txn
-    slot (read/lock batches) or carry an out-of-range address (write
-    batch) — ``ops.commit_fused`` owns those conventions.
+                      tids, r_clocks, commit_ver, *, n_words: int,
+                      mode: int = MODE_LE, tile: int = 1024,
+                      interpret: bool = False):
+    """heap: [R, 128] rows of a 32-bit dtype; write batch [N] (N a
+    multiple of ``tile``, int32 addrs ASCENDING and segs, values
+    heap.dtype); lock batch [L]; read batch [M]; txn vectors [T]
+    (int32); commit_ver: [1] int32 (REBASED — 0 by the wrapper's
+    convention).  Returns ``(heap' [R, 128], ok [T] int32, lver' [L]
+    int32)``.  Pad rows must point their seg at a dummy txn slot
+    (read/lock batches) or carry an address at or past ``n_words``
+    (write batch) — ``ops.commit_fused`` owns those conventions.
     """
-    (h,) = heap.shape
     n = w_addr.shape[0]
+    assert heap.ndim == 2 and heap.shape[1] == LANES, heap.shape
     assert n % tile == 0, (n, tile)
-    grid = (n // tile,)
     t = tids.shape[0]
     L = l_ver.shape[0]
+    smem = pltpu.SMEM
+    tiled = pl.BlockSpec((tile,), lambda i: (i,), memory_space=smem)
+    const = lambda s: pl.BlockSpec((s,), lambda i: (0,),    # noqa: E731
+                                   memory_space=smem)
     m = r_ver.shape[0]
-    const = lambda s: pl.BlockSpec((s,), lambda i: (0,))   # noqa: E731
-    tiled = pl.BlockSpec((tile,), lambda i: (i,))
     return pl.pallas_call(
-        lambda *refs: _fused_kernel(mode, h, *refs),
-        grid=grid,
+        lambda *refs: _fused_kernel(mode, n_words, *refs),
+        grid=(n // tile,),
         in_specs=[
-            const(h),                      # heap
             tiled, tiled, tiled,           # w_addr, w_val, w_seg
             const(L), const(L), const(L), const(L),   # l_*
             const(m), const(m), const(m), const(m), const(m),  # r_*
             const(t), const(t),            # tids, r_clocks
             const(1),                      # commit_ver
+            pl.BlockSpec(memory_space=pl.ANY),        # heap
         ],
-        out_specs=[const(h), const(t), const(L)],
+        out_specs=[pl.BlockSpec(memory_space=pl.ANY), const(t), const(L)],
         out_shape=[
-            jax.ShapeDtypeStruct((h,), heap.dtype),
+            jax.ShapeDtypeStruct(heap.shape, heap.dtype),
             jax.ShapeDtypeStruct((t,), jnp.int32),
             jax.ShapeDtypeStruct((L,), jnp.int32),
         ],
+        scratch_shapes=[pltpu.VMEM((1, LANES), heap.dtype),
+                        pltpu.SemaphoreType.DMA(())],
+        input_output_aliases={15: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(heap, w_addr, w_val, w_seg, l_ver, l_own, l_meta, l_seg,
-      r_ver, r_own, r_meta, r_seen, r_seg, tids, r_clocks, commit_ver)
+    )(w_addr, w_val, w_seg, l_ver, l_own, l_meta, l_seg,
+      r_ver, r_own, r_meta, r_seen, r_seg, tids, r_clocks, commit_ver,
+      heap)

@@ -72,11 +72,11 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 
 def flash_attention_nhd(q, k, v, *, causal: bool, block_q: int = 128,
                         block_k: int = 128, scale=None,
-                        interpret: bool = True):
+                        interpret: bool = False):
     """q: [N, Sq, D]; k, v: [N, Sk, D] (N = batch*heads, kv pre-repeated).
 
-    Returns [N, Sq, D].  ``interpret=True`` executes on CPU; on a real TPU
-    pass interpret=False.
+    Returns [N, Sq, D].  ``interpret=True`` runs it in the Pallas
+    interpreter (tests on CPU).
     """
     N, Sq, D = q.shape
     Sk = k.shape[1]

@@ -46,7 +46,7 @@ def _fused_kernel(slot_ref, scal_ref, p_ref, g_ref, m_ref, v_ref, ring_ref,
 
 def fused_adamw_flat(p, g, m, v, ring, slot, *, lr, scale, b1c, b2c,
                      b1, b2, eps, wd, tile: int = 2048,
-                     interpret: bool = True):
+                     interpret: bool = False):
     """p: [n] params; g: [n] f32 grads; m, v: [n] f32 moments;
     ring: [R, n] or None; slot: int32 ring row to write.
 

@@ -1,29 +1,44 @@
 """jit'd public wrappers over the Pallas kernels.
 
-These adapt model-layer shapes (GQA heads, parameter pytrees, ring dicts)
-to the flat kernel interfaces.  ``interpret`` defaults to True so the whole
-suite runs on CPU; TPU deployments flip it via KERNEL_INTERPRET=0.
+These adapt engine and model-layer shapes (ragged batches, GQA heads,
+parameter pytrees, ring dicts) to the flat kernel interfaces, and own
+the one dispatch decision: ``on_tpu()``.  On a TPU every wrapper calls
+its kernel compiled for the chip; elsewhere the engine sites keep their
+numpy twins, and the wrappers that stand on the CPU path themselves
+(``commit_fused``, ``publish_row``, ``snapshot_select`` and the model
+kernels) take their twin or pure-jnp reference.  ``interpret=True``
+runs a kernel in the Pallas interpreter instead; only tests ask for it.
+
+``COUNTS`` records, per kernel, how often a wrapper entered the kernel
+branch and how often a batch took the int64 numpy twin instead.
 """
 from __future__ import annotations
 
+import collections
 import functools
-import os
+import threading
 import warnings
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.kernels import commit_fused as _cf
 from repro.kernels import fused_adamw as _fa
 from repro.kernels import flash_attention as _fl
 from repro.kernels import gather_read as _gr
+from repro.kernels import ref as _ref
 from repro.kernels import scatter_write as _sw
 from repro.kernels import snapshot_select as _ss
 from repro.kernels import ssd_scan as _ssd
 from repro.kernels import validate as _val
 from repro.kernels import version_select as _vs
 
-INTERPRET = os.environ.get("KERNEL_INTERPRET", "1") != "0"
+#: lane width of every lane-dense kernel layout
+LANES = 128
+#: smallest kernel batch: one (8, 128) int32 block
+MIN_TILE = 8 * LANES
+_LO32, _HI32 = -(1 << 31) + 1, (1 << 31) - 1
 
 # the donated publish paths below request buffer donation unconditionally
 # (on TPU it makes the heap/ring update in-place); the CPU backend cannot
@@ -32,8 +47,88 @@ warnings.filterwarnings(
     "ignore", message="Some donated buffers were not usable")
 
 
+@functools.cache
+def on_tpu() -> bool:
+    """True when JAX's default backend is a TPU: the kernels then run
+    compiled for the chip.  The only place the platform is consulted."""
+    return jax.default_backend() == "tpu"
+
+
+class KernelCounts:
+    """Per-kernel counts of kernel-branch entries and int64 twin routes
+    (batches holding words beyond int32, which the x64-less device path
+    would truncate).  Thread-safe; ``reset`` starts a new window."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self.entries = collections.Counter()
+        self.twin_routes = collections.Counter()
+
+    def enter(self, kernel: str) -> None:
+        with self._lock:
+            self.entries[kernel] += 1
+
+    def twin(self, kernel: str) -> None:
+        with self._lock:
+            self.twin_routes[kernel] += 1
+
+    def reset(self) -> None:
+        with self._lock:
+            self.entries.clear()
+            self.twin_routes.clear()
+
+
+COUNTS = KernelCounts()
+
+
+def beyond_int32(a) -> bool:
+    """True when an int64 array holds a value an int32 cannot."""
+    a = np.asarray(a)
+    return bool(a.dtype == np.int64 and a.size
+                and (int(a.max()) > _HI32 or int(a.min()) < _LO32))
+
+
+def tile_for(n: int, tile: int) -> int:
+    """Elements per grid step for an ``n``-element batch: a power of two
+    from one (8, 128) block up to ``tile``, never below one block (the
+    chip refuses smaller blocks), so ragged batches pad up to it."""
+    return max(MIN_TILE, min(tile, 1 << (max(n, 1) - 1).bit_length()))
+
+
+def _padded(x, length: int, fill):
+    """``x`` (host or device) padded at the end to ``length`` elements
+    along axis 0."""
+    pad = length - x.shape[0]
+    if not pad:
+        return x
+    widths = [(0, pad)] + [(0, 0)] * (x.ndim - 1)
+    if isinstance(x, np.ndarray):
+        return np.pad(x, widths, constant_values=fill)
+    return jnp.pad(x, widths, constant_values=fill)
+
+
+def _rows(x):
+    """[n] -> [ceil(n / 128), 128], zero-padded (a heap row's layout)."""
+    n = x.shape[0]
+    return _padded(x, -(-n // LANES) * LANES, 0).reshape(-1, LANES)
+
+
+def _sorted_batch(addrs, values, n_words: int, tile: int):
+    """Sort a write batch by address (the scatter kernels walk rows in
+    order) and pad it to a whole number of tiles with ``n_words``, an
+    address the kernels skip.  Returns ``(order, addrs, values, tile)``
+    with ``addrs`` int64 and ``tile`` the grid step."""
+    order = np.argsort(addrs, kind="stable")
+    n = addrs.shape[0]
+    t = tile_for(n, tile)
+    length = -(-max(n, 1) // t) * t
+    a = _padded(np.asarray(addrs, np.int64)[order], length, n_words)
+    v = _padded(np.asarray(values)[order], length, 0)
+    return order, a, v, t
+
+
 def flash_attention(q, k, v, *, causal: bool, block_q: int = 128,
-                    block_k: int = 128):
+                    block_k: int = 128, interpret: bool = False):
     """q: [B, S, H, D]; k, v: [B, Sk, KV, D] -> [B, S, H, D] (GQA)."""
     B, Sq, H, D = q.shape
     Sk, KV = k.shape[1], k.shape[2]
@@ -43,93 +138,109 @@ def flash_attention(q, k, v, *, causal: bool, block_q: int = 128,
         B * H, Sk, D)
     vf = jnp.repeat(v.transpose(0, 2, 1, 3), G, axis=1).reshape(
         B * H, Sk, D)
-    o = _fl.flash_attention_nhd(qf, kf, vf, causal=causal,
-                                block_q=block_q, block_k=block_k,
-                                interpret=INTERPRET)
+    if interpret or on_tpu():
+        o = _fl.flash_attention_nhd(qf, kf, vf, causal=causal,
+                                    block_q=block_q, block_k=block_k,
+                                    interpret=interpret)
+    else:
+        o = _ref.flash_attention_ref(qf, kf, vf, causal=causal)
     return o.reshape(B, H, Sq, D).transpose(0, 2, 1, 3)
 
 
-def ssd_scan(xh, dt, A, B_, C_, *, chunk: int = 256, init_state=None):
+def ssd_scan(xh, dt, A, B_, C_, *, chunk: int = 256, init_state=None,
+             interpret: bool = False):
     """Kernel chunk-scan; final state recomputed via the jnp path when a
     carry is required (see ssd_scan.py)."""
     assert init_state is None, "kernel path serves the no-carry hot loop"
+    if not (interpret or on_tpu()):
+        return _ref.ssd_scan_ref(xh, dt, A, B_, C_)[0], None
     y = _ssd.ssd_scan_pallas(xh, dt, A, B_, C_, chunk=chunk,
-                             interpret=INTERPRET)
+                             interpret=interpret)
     return y, None
 
 
-def snapshot_select(ring, ts, read_clock):
+def snapshot_select(ring, ts, read_clock, *, interpret: bool = False):
     """ring: [R, *shape] -> (value [*shape], ok)."""
     R = ring.shape[0]
     shape = ring.shape[1:]
-    n = 1
-    for s in shape:
-        n *= s
-    flat = ring.reshape(R, n)
-    tile = n
-    for cand in (2048, 1024, 512, 256, 128, 64, 32, 16, 8, 4, 2, 1):
-        if n % cand == 0:
-            tile = cand
-            break
-    val, ok = _ss.snapshot_select_flat(flat, ts, read_clock, tile=tile,
-                                       interpret=INTERPRET)
+    flat = ring.reshape(R, -1)
+    if interpret or on_tpu():
+        COUNTS.enter("snapshot_select")
+        val, ok = _ss.snapshot_select_flat(flat, ts, read_clock,
+                                           interpret=interpret)
+    else:
+        val, ok = _ref.snapshot_select_ref(flat, ts, read_clock)
     return val.reshape(shape), ok
 
 
-def snapshot_read(heap, addrs, tile: int = 512):
+@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+def _gather(heap, addrs, *, tile, interpret):
+    return _gr.gather_read_flat(_rows(heap), addrs, tile=tile,
+                                interpret=interpret).reshape(-1)
+
+
+def snapshot_read(heap, addrs, tile: int = 1024, *,
+                  interpret: bool = False):
     """Batched snapshot read: ``heap[addrs]`` in one gather launch.
 
-    ``heap``: [H] (any numeric dtype); ``addrs``: [N] int — returns the
-    [N] gathered values as a jax array.  Adapts ragged batch lengths to
-    the tiled kernel by padding with address 0 (always allocated — the
-    heaps burn it as NULL) and slicing the result back to N.  This is the
-    `Txn.read_bulk` / `snapshot_bulk` hot path on TPU
-    (KERNEL_INTERPRET=0); on CPU the engine uses the numpy twin (a single
-    fancy-index in ``engine.bulkread.heap_gather``) directly.
+    ``heap``: [H] of a 32-bit dtype, or a host int64 heap of int32-range
+    words (one holding a wider word raises OverflowError: the device copy
+    would truncate it, and the engine routes such heaps to the numpy
+    twin first); ``addrs``: [N] int — returns the [N] gathered values as
+    a jax array.  Adapts ragged batch lengths to the tiled kernel by
+    padding with address 0 (always allocated — the heaps burn it as
+    NULL) and slicing the result back to N.  This is the
+    `Txn.read_bulk` / `snapshot_bulk` hot path on
+    TPU; on CPU the engine uses the numpy twin (a single fancy-index in
+    ``engine.bulkread.heap_gather``) directly.
     """
     n = int(addrs.shape[0])
+    if isinstance(heap, np.ndarray) and beyond_int32(heap):
+        raise OverflowError("heap holds a word beyond int32")
+    hj = jnp.asarray(heap)
     if n == 0:
-        return jnp.zeros((0,), heap.dtype)
-    t = min(tile, 1 << (n - 1).bit_length())
-    pad = (-n) % t
-    a = jnp.asarray(addrs, jnp.int32)
-    if pad:
-        a = jnp.pad(a, (0, pad), constant_values=_gr.PAD_ADDR)
-    out = _gr.gather_read_flat(jnp.asarray(heap), a, tile=t,
-                               interpret=INTERPRET)
-    return out[:n]
+        return jnp.zeros((0,), hj.dtype)
+    COUNTS.enter("gather_read")
+    t = tile_for(n, tile)
+    a = _padded(np.asarray(addrs, np.int32), -(-n // t) * t, _gr.PAD_ADDR)
+    return _gather(hj, jnp.asarray(a), tile=t, interpret=interpret)[:n]
 
 
-def write_back(heap, addrs, values, tile: int = 512):
+@functools.partial(jax.jit, donate_argnums=(0,),
+                   static_argnames=("tile", "interpret"))
+def _scatter(row, addrs, values, *, tile, interpret):
+    n = row.shape[0]
+    out = _sw.scatter_write_flat(_rows(row), addrs, values, n_words=n,
+                                 tile=tile, interpret=interpret)
+    return out.reshape(-1)[:n]
+
+
+def _scatter_launch(row, addrs_np, values, tile, interpret):
+    """Sort, pad and launch one scatter kernel over a jax row."""
+    _, a, v, t = _sorted_batch(addrs_np, np.asarray(values),
+                               int(row.shape[0]), tile)
+    COUNTS.enter("scatter_write")
+    return _scatter(row, jnp.asarray(a, jnp.int32),
+                    jnp.asarray(v, row.dtype), tile=t, interpret=interpret)
+
+
+def write_back(heap, addrs, values, tile: int = 1024, *,
+               interpret: bool = False):
     """Batched commit write-back: ``heap[addrs] = values`` in one launch.
 
-    ``heap``: [H] (any numeric dtype); ``addrs``: [N] int (unique —
-    write sets are dict-keyed); ``values``: [N] — returns the [H]
-    updated row as an ndarray.  Adapts ragged batch lengths to the tiled
-    kernel by padding with the one-past-the-end address (dropped by jax
-    scatter semantics, so padding never clobbers a live word) and guards
-    the int64 range per the ``version_select`` pattern: without jax x64
-    the kernel would silently truncate int64 payloads — AND addresses —
-    to int32, so such batches take the numpy twin
-    (``scatter_write.np_write_back``, exact at any width) instead; an
-    out-of-range address then raises there rather than truncating and
-    scattering to the wrong word.  This is the commit-pipeline hot path
-    on TPU (KERNEL_INTERPRET=0); on CPU the engine scatters through the
-    numpy heap directly (``ArrayHeap.scatter``).
+    ``heap``: [H] (a 32-bit dtype, or an int64 host heap); ``addrs``:
+    [N] int (unique — write sets are dict-keyed); ``values``: [N] —
+    returns the [H] updated row as an ndarray.  Guards the int64 range
+    per the ``version_select`` pattern: without jax x64 the kernel would
+    silently truncate int64 payloads — AND addresses — to int32, so such
+    batches take the numpy twin (``scatter_write.np_write_back``, exact
+    at any width) instead; an out-of-range address then raises there
+    rather than truncating and scattering to the wrong word.
     """
-    import numpy as np
-
     vals = np.asarray(values)
     addrs_np = np.asarray(addrs, np.int64)
-    n = int(addrs_np.shape[0])
-    if n == 0:
+    if addrs_np.shape[0] == 0:
         return np.array(np.asarray(heap), copy=True)
-    lo, hi = -(1 << 31) + 1, (1 << 31) - 1
-
-    def _beyond_int32(a):
-        return a.dtype == np.int64 and a.size and \
-            (int(a.max()) > hi or int(a.min()) < lo)
-
     # heap CONTENTS are scanned only for host-side heaps: a jax int64
     # heap can only exist with x64 enabled, where ``jnp.asarray`` cannot
     # truncate it — so the device hot path (``scatter_row``) never pays
@@ -139,19 +250,12 @@ def write_back(heap, addrs, values, tile: int = 512):
     if not isinstance(heap, (np.ndarray, jax.Array)):
         heap = np.asarray(heap)            # lists/tuples: normalize once
     heap_np = heap if isinstance(heap, np.ndarray) else None
-    if _beyond_int32(vals) or _beyond_int32(addrs_np) \
-            or (heap_np is not None and _beyond_int32(heap_np)):
+    if beyond_int32(vals) or beyond_int32(addrs_np) \
+            or (heap_np is not None and beyond_int32(heap_np)):
+        COUNTS.twin("scatter_write")
         return _sw.np_write_back(np.asarray(heap), addrs_np, vals)
-    t = min(tile, 1 << (n - 1).bit_length())
-    pad = (-n) % t
-    hj = jnp.asarray(heap)
-    a = jnp.asarray(addrs_np, jnp.int32)
-    v = jnp.asarray(vals, hj.dtype)
-    if pad:
-        a = jnp.pad(a, (0, pad), constant_values=int(hj.shape[0]))
-        v = jnp.pad(v, (0, pad))
-    out = _sw.scatter_write_flat(hj, a, v, tile=t, interpret=INTERPRET)
-    return np.asarray(out)
+    hj = jnp.array(heap)                   # a copy: the launch donates it
+    return np.asarray(_scatter_launch(hj, addrs_np, vals, tile, interpret))
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -159,13 +263,8 @@ def _publish_row_xla(row, addrs, values):
     return row.at[addrs].set(values)
 
 
-@functools.partial(jax.jit, donate_argnums=(0,), static_argnames=("tile",))
-def _publish_row_pallas(row, addrs, values, *, tile):
-    return _sw.scatter_write_flat(row, addrs, values, tile=tile,
-                                  interpret=INTERPRET)
-
-
-def publish_row(row, addrs, values, tile: int = 512):
+def publish_row(row, addrs, values, tile: int = 1024, *,
+                interpret: bool = False):
     """Device-resident row publish: ``row.at[addrs].set(values)`` with
     the input row DONATED.
 
@@ -176,39 +275,34 @@ def publish_row(row, addrs, values, tile: int = 512):
     jax array, the jit requests donation of the row buffer (in-place on
     backends that honor it; the CPU backend ignores the request), and
     no host materialization of the row happens at any width the caller
-    admits.  The caller owns the bounds check and the int64-range guard
-    (``scatter_row`` / ``commit_fused`` route guarded batches to the
-    numpy twins) — and, on device runtimes, ownership of ``row``: a
-    donated buffer is invalidated, so snapshot-pinned readers must be
-    handed a fresh alias first (see ``MVStoreHandle._install``).
+    admits.  On TPU the scatter_write kernel runs; elsewhere the jitted
+    jnp scatter.  The caller owns the bounds check and the int64-range
+    guard (``scatter_row`` routes guarded batches to the numpy twin) —
+    and, on device runtimes, ownership of ``row``: a donated buffer is
+    invalidated, so snapshot-pinned readers must be handed a fresh
+    alias first (see ``MVStoreHandle._install``).
     """
-    import numpy as np
-
     a_np = np.asarray(addrs, np.int64)
-    n = int(a_np.shape[0])
     rj = jnp.asarray(row)
-    if n == 0:
+    if a_np.shape[0] == 0:
         return rj
-    if not INTERPRET:
-        t = min(tile, 1 << (n - 1).bit_length())
-        pad = (-n) % t
-        a = jnp.asarray(a_np, jnp.int32)
-        v = jnp.asarray(values, rj.dtype)
-        if pad:
-            a = jnp.pad(a, (0, pad), constant_values=int(rj.shape[0]))
-            v = jnp.pad(v, (0, pad))
-        return _publish_row_pallas(rj, a, v, tile=t)
+    if interpret or on_tpu():
+        return _scatter_launch(rj, a_np, values, tile, interpret)
     return _publish_row_xla(rj, jnp.asarray(a_np),
                             jnp.asarray(values, rj.dtype))
 
 
 @functools.partial(jax.jit, donate_argnums=(0,),
-                   static_argnames=("mode", "tile"))
+                   static_argnames=("mode", "tile", "interpret"))
 def _commit_fused_jit(heap, wa, wv, ws, lv, lo, lm, ls,
-                      rv, ro, rm, rn, rs, tids, rcs, cv, *, mode, tile):
-    return _cf.commit_fused_flat(
-        heap, wa, wv, ws, lv, lo, lm, ls, rv, ro, rm, rn, rs,
-        tids, rcs, cv, mode=mode, tile=tile, interpret=INTERPRET)
+                      rv, ro, rm, rn, rs, tids, rcs, cv, *, mode, tile,
+                      interpret):
+    n = heap.shape[0]
+    out, ok, lver = _cf.commit_fused_flat(
+        _rows(heap), wa, wv, ws, lv, lo, lm, ls, rv, ro, rm, rn, rs,
+        tids, rcs, cv, n_words=n, mode=mode, tile=tile,
+        interpret=interpret)
+    return out.reshape(-1)[:n], ok, lver
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1))
@@ -223,8 +317,9 @@ def _ring_refresh(ring, ring_ts, row, slot, ts):
 def commit_fused(heap, w_addr, w_val, w_seg,
                  l_words, l_seg, r_words, r_seen, r_seg,
                  tids, r_clocks, commit_ver, n_txn, *,
-                 mode=None, tile: int = 512,
-                 ring=None, ring_ts=None, ring_slot=None):
+                 mode=None, tile: int = 1024,
+                 ring=None, ring_ts=None, ring_slot=None,
+                 interpret: bool = False):
     """Group-commit megakernel: validate + claim-check + scatter + stamp
     for a batch of conflict-disjoint transactions in ONE launch.
 
@@ -237,7 +332,7 @@ def commit_fused(heap, w_addr, w_val, w_seg,
     per-member identity and snapshot.  Returns ``(new_heap, txn_ok,
     new_l_words)`` — ``new_heap`` a jax array (device-resident, heap
     buffer donated; never materialized to host here; the exact ndarray
-    when the batch routes to the numpy twin), ``txn_ok`` a
+    when the batch routes to the int64 numpy twin), ``txn_ok`` a
     bool[n_txn] ndarray, ``new_l_words`` exact int64 release words:
     ``commit_ver`` stamped unlocked where the member survived, the
     original word otherwise.  With ``ring``/``ring_ts``/``ring_slot``
@@ -250,27 +345,18 @@ def commit_fused(heap, w_addr, w_val, w_seg,
     deltas) and the release words are reconstructed host-side at full
     width; batches whose payloads/addresses exceed int32 route to the
     in-file numpy twin (``np_commit_fused``) exactly like
-    ``write_back``, as does an int64-range host heap.
+    ``write_back``, as does an int64-range host heap.  Off the TPU the
+    twin serves every batch (its result returns to the heap's device
+    when the heap was a jax array).
     """
-    import numpy as np
-
-    from repro.core.engine.arrayheap import (_TID_BIAS, _TID_MASK,
-                                             _UNLOCKED_WORD, _VER_SHIFT)
+    from repro.core.engine.arrayheap import (_UNLOCKED_WORD, _VER_SHIFT,
+                                             unpack_words)
 
     if mode is None:
         mode = _cf.MODE_LE
     base = int(commit_ver)
-    lo32, hi32 = -(1 << 31) + 1, (1 << 31) - 1
-
-    def unpack(words):
-        w = np.asarray(words, np.int64)
-        ver = w >> _VER_SHIFT
-        own = (((w >> 2) & _TID_MASK) - _TID_BIAS).astype(np.int32)
-        meta = (((w >> 1) & 1) | ((w & 1) << 1)).astype(np.int32)
-        return ver, own, meta
-
-    l_ver, l_own, l_meta = unpack(l_words)
-    r_ver, r_own, r_meta = unpack(r_words)
+    l_ver, l_own, l_meta = unpack_words(l_words)
+    r_ver, r_own, r_meta = unpack_words(r_words)
     w_addr = np.asarray(w_addr, np.int64)
     w_seg = np.asarray(w_seg, np.int64)
     l_seg = np.asarray(l_seg, np.int64)
@@ -284,37 +370,33 @@ def commit_fused(heap, w_addr, w_val, w_seg,
                         | np.int64(_UNLOCKED_WORD),
                         np.asarray(l_words, np.int64))
 
-    def _beyond_int32(a):
-        return a.dtype == np.int64 and a.size and \
-            (int(a.max()) > hi32 or int(a.min()) < lo32)
-
     if not isinstance(heap, (np.ndarray, jax.Array)):
         heap = np.asarray(heap)
     heap_np = heap if isinstance(heap, np.ndarray) else None
-    if _beyond_int32(vals) or _beyond_int32(w_addr) \
-            or (heap_np is not None and _beyond_int32(heap_np)):
+    wide = beyond_int32(vals) or beyond_int32(w_addr) \
+        or (heap_np is not None and beyond_int32(heap_np))
+    if wide or not (interpret or on_tpu()):
+        if wide:
+            COUNTS.twin("commit_fused")
         new_heap, ok, _ = _cf.np_commit_fused(
             np.asarray(heap), w_addr, vals, w_seg,
             l_ver, l_own, l_meta, l_seg,
             r_ver, r_own, r_meta, r_seen, r_seg,
             tids, r_clocks, base, n_txn, mode)
-        # stay numpy on this route: jnp.asarray without x64 would
-        # truncate the very int64 payloads that routed us here
+        # an int64 batch stays numpy: jnp.asarray without x64 would
+        # truncate the very payloads that routed it here
+        if not wide and heap_np is None:
+            new_heap = jax.device_put(new_heap, heap.sharding)
         out = (new_heap, ok, stamp(ok))
     else:
+        COUNTS.enter("commit_fused")
         hj = jnp.asarray(heap)
-        h = int(hj.shape[0])
-        n = int(w_addr.shape[0])
-        t = min(tile, 1 << (max(n, 1) - 1).bit_length())
-        pad = (-n) % t if n else t        # >=1 grid step runs the verdict
-        a32 = np.concatenate([w_addr, np.full(pad, h, np.int64)])
-        s32 = np.concatenate([w_seg, np.zeros(pad, np.int64)])
-        v = jnp.concatenate([jnp.asarray(vals, hj.dtype),
-                             jnp.zeros((pad,), hj.dtype)]) if pad \
-            else jnp.asarray(vals, hj.dtype)
+        order, a32, v, t = _sorted_batch(w_addr, vals, int(hj.shape[0]),
+                                         tile)
+        s32 = _padded(w_seg[order], a32.shape[0], 0)
 
         def rel(x):
-            return np.clip(np.asarray(x, np.int64) - base, lo32, hi32)
+            return np.clip(np.asarray(x, np.int64) - base, _LO32, _HI32)
 
         # dummy txn slot T absorbs the pad rows of empty side batches
         tids_p = np.concatenate([np.asarray(tids, np.int64), [0]])
@@ -337,11 +419,11 @@ def commit_fused(heap, w_addr, w_val, w_seg,
             return jnp.asarray(np.asarray(x), jnp.int32)
 
         new_heap, ok32, _ = _commit_fused_jit(
-            hj, i32(a32), v, i32(s32),
+            hj, i32(a32), jnp.asarray(v, hj.dtype), i32(s32),
             i32(lv), i32(lo_), i32(lm), i32(ls),
             i32(rv), i32(ro), i32(rm), i32(rn), i32(rs),
             i32(tids_p), i32(rcs_p), jnp.zeros((1,), jnp.int32),
-            mode=int(mode), tile=t)
+            mode=int(mode), tile=t, interpret=interpret)
         ok = np.asarray(ok32[:n_txn]) != 0
         out = (new_heap, ok, stamp(ok))
     if ring is None:
@@ -355,14 +437,23 @@ def commit_fused(heap, w_addr, w_val, w_seg,
     return new_heap, ok, new_l, new_ring, new_ts
 
 
+@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+def _validate(ver, own, meta, seen, params, *, tile, interpret):
+    mask = _val.validate_readset_flat(
+        ver.reshape(-1, LANES), own.reshape(-1, LANES),
+        meta.reshape(-1, LANES), seen.reshape(-1, LANES),
+        params[0], params[1], params[2], tile=tile, interpret=interpret)
+    return jnp.all(mask == 1)
+
+
 def validate_readset(ver, own, meta, seen, r_clock, tid, mode,
-                     tile: int = 512) -> bool:
+                     tile: int = 1024, *, interpret: bool = False) -> bool:
     """Bulk read-set validation: True iff every entry is still valid.
 
     Adapts ragged read-set lengths to the tiled kernel by padding with
     always-valid entries (see ``validate.PAD``), then AND-reduces the
-    per-entry mask.  The engine calls this on the TPU path
-    (KERNEL_INTERPRET=0); on CPU it uses the numpy twin directly.
+    per-entry mask.  The engine calls this on the TPU path; on CPU it
+    uses the numpy twin directly.
 
     Versions are rebased to ``r_clock`` before the int32 cast: the packed
     lock word carries a 46-bit version and the clock bumps on every
@@ -373,31 +464,40 @@ def validate_readset(ver, own, meta, seen, r_clock, tid, mode,
     comparison's sign (a clamped entry is >= 2^31 commits away from the
     snapshot, i.e. unambiguously stale/fresh).
     """
-    import numpy as np
-
     n = int(ver.shape[0])
     if n == 0:
         return True
+    COUNTS.enter("validate")
     base = int(r_clock)
-    lo, hi = -(1 << 31) + 1, (1 << 31) - 1
-    ver_rel = np.clip(np.asarray(ver, np.int64) - base, lo, hi)
-    seen_rel = np.clip(np.asarray(seen, np.int64) - base, lo, hi)
-    t = min(tile, 1 << (n - 1).bit_length())
-    pad = (-n) % t
+    t = tile_for(n, tile)
+    length = -(-n // t) * t
     p = _val.PAD
 
     def prep(x, fill):
-        x = jnp.asarray(np.asarray(x), jnp.int32)
-        return jnp.pad(x, (0, pad), constant_values=fill) if pad else x
+        return jnp.asarray(_padded(np.asarray(x).astype(np.int32), length,
+                                   fill))
 
-    mask = _val.validate_readset_flat(
-        prep(ver_rel, p["ver"]), prep(own, p["own"]),
-        prep(meta, p["meta"]), prep(seen_rel, p["seen"]),
-        0, int(tid), int(mode), tile=t, interpret=INTERPRET)
-    return bool(jnp.all(mask == 1))
+    def rel(x):
+        return np.clip(np.asarray(x, np.int64) - base, _LO32, _HI32)
+
+    return bool(_validate(
+        prep(rel(ver), p["ver"]), prep(own, p["own"]),
+        prep(meta, p["meta"]), prep(rel(seen), p["seen"]),
+        jnp.asarray([0, int(tid), int(mode)], jnp.int32),
+        tile=t, interpret=interpret))
 
 
-def version_select(ts, data, r_clock, tile: int = 256):
+@functools.partial(jax.jit, static_argnames=("tile", "interpret"))
+def _version_select(ts, data, *, tile, interpret):
+    depth = ts.shape[1]
+    slots = lambda x: x.T.reshape(depth, -1, LANES)    # noqa: E731
+    vals, ok = _vs.version_select_flat(slots(ts), slots(data), 0,
+                                       tile=tile, interpret=interpret)
+    return vals.reshape(-1), ok.reshape(-1)
+
+
+def version_select(ts, data, r_clock, tile: int = 1024, *,
+                   interpret: bool = False):
     """Batched snapshot version select over packed VLT mirror rows.
 
     ``ts``/``data``: [N, D] newest-first (timestamps int, data numeric);
@@ -408,57 +508,57 @@ def version_select(ts, data, r_clock, tile: int = 256):
     ``r_clock`` before the int32 cast (absolute clocks exceed int32 in
     long runs; only the sign of ``ts - r_clock`` matters — same
     treatment as ``validate_readset``).  This is the Mode-U bulk
-    versioned-read hot path on TPU (KERNEL_INTERPRET=0); on CPU the
-    engine uses the numpy twin (``core.vlt.np_version_select``)
-    directly.
+    versioned-read hot path on TPU; on CPU the engine uses the numpy
+    twin (``core.vlt.np_version_select``) directly.
     """
-    import numpy as np
-
     n = int(ts.shape[0])
     if n == 0:
         return (np.zeros((0,), np.int64), np.zeros((0,), bool))
-    lo, hi = -(1 << 31) + 1, (1 << 31) - 1
     data = np.asarray(data)
-    if data.dtype == np.int64 and data.size and \
-            (int(data.max()) > hi or int(data.min()) < lo):
+    if beyond_int32(data):
         # without jax x64 the kernel would silently truncate int64
         # payloads to int32 — wrong values with ok=True; such batches
         # take the numpy twin (exact at any width) instead
         from repro.core.vlt import np_version_select
+        COUNTS.twin("version_select")
         return np_version_select(np.asarray(ts, np.int64), data,
                                  int(r_clock))
-    rel = np.clip(np.asarray(ts, np.int64) - int(r_clock), lo, hi)
-    t = min(tile, 1 << (n - 1).bit_length())
-    pad = (-n) % t
-    rel = jnp.asarray(rel, jnp.int32)
-    d = jnp.asarray(data)
-    if pad:
-        rel = jnp.pad(rel, ((0, pad), (0, 0)), constant_values=_vs.PAD_TS)
-        d = jnp.pad(d, ((0, pad), (0, 0)))
-    vals, ok = _vs.version_select_flat(rel, d, 0, tile=t,
-                                       interpret=INTERPRET)
+    COUNTS.enter("version_select")
+    rel = np.clip(np.asarray(ts, np.int64) - int(r_clock), _LO32, _HI32)
+    t = tile_for(n, tile)
+    length = -(-n // t) * t
+    vals, ok = _version_select(
+        jnp.asarray(_padded(rel.astype(np.int32), length, _vs.PAD_TS)),
+        jnp.asarray(_padded(data, length, 0), jnp.int32),
+        tile=t, interpret=interpret)
     return np.asarray(vals[:n]), np.asarray(ok[:n]) != 0
 
 
 def fused_adamw(p, g, m, v, ring, slot, *, lr, scale, count, b1, b2, eps,
-                wd):
+                wd, interpret: bool = False):
     """Pytree-leaf fused update.  p: any shape; ring: [R, *p.shape]|None."""
     shape = p.shape
     n = p.size
     cnt = count.astype(jnp.float32)
     b1c = 1 - b1 ** cnt
     b2c = 1 - b2 ** cnt
-    tile = n
-    for cand in (2048, 1024, 512, 256, 128, 64, 32, 16, 8, 4, 2, 1):
-        if n % cand == 0:
-            tile = cand
-            break
     rf = ring.reshape(ring.shape[0], n) if ring is not None else None
-    p2, m2, v2, r2 = _fa.fused_adamw_flat(
-        p.reshape(n), g.reshape(n), m.reshape(n), v.reshape(n), rf,
-        jnp.asarray(slot, jnp.int32), lr=jnp.asarray(lr),
-        scale=jnp.asarray(scale), b1c=b1c, b2c=b2c, b1=b1, b2=b2, eps=eps,
-        wd=wd, tile=tile, interpret=INTERPRET)
+    if interpret or on_tpu():
+        tile = n
+        for cand in (2048, 1024, 512, 256, 128, 64, 32, 16, 8, 4, 2, 1):
+            if n % cand == 0:
+                tile = cand
+                break
+        p2, m2, v2, r2 = _fa.fused_adamw_flat(
+            p.reshape(n), g.reshape(n), m.reshape(n), v.reshape(n), rf,
+            jnp.asarray(slot, jnp.int32), lr=jnp.asarray(lr),
+            scale=jnp.asarray(scale), b1c=b1c, b2c=b2c, b1=b1, b2=b2,
+            eps=eps, wd=wd, tile=tile, interpret=interpret)
+    else:
+        p2, m2, v2, r2 = _ref.fused_adamw_ref(
+            p.reshape(n), g.reshape(n), m.reshape(n), v.reshape(n), rf,
+            slot, lr=lr, scale=scale, b1c=b1c, b2c=b2c, b1=b1, b2=b2,
+            eps=eps, wd=wd)
     p2 = p2.reshape(shape)
     m2 = m2.reshape(shape)
     v2 = v2.reshape(shape)

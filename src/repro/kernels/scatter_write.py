@@ -8,21 +8,20 @@ round-trips:
 
     out = heap;  out[addrs[i]] = values[i]      for i in [0, N)
 
-Layout mirrors the gather kernel: the heap rides in as one full block
-(KBs..MBs at this repro's scales), the address/value vectors are tiled
-over the grid, and the OUTPUT is the full heap block revisited by every
-grid step (constant index map) — step 0 copies the heap through, each
-step then scatters its tile into the block, so the final block holds
-every update.  Addresses are the caller's responsibility to keep unique
-(write sets are dict-keyed, so they are); an out-of-range address is
-DROPPED by jax scatter semantics, which is exactly what the ragged-batch
-padding relies on (``ops.write_back`` pads with ``heap.size``, one past
-the end).
+Layout mirrors the gather kernel: the heap stays in HBM as
+``[H / 128, 128]`` rows and is aliased to the output, so the update is
+in place on the (copied or donated) buffer.  Addresses and values ride
+in as SMEM blocks of ``tile``, SORTED by address (the ``ops`` wrapper
+sorts them; write sets are dict-keyed, so addresses are unique).  The
+kernel walks them in order, keeping one heap row in VMEM: a run of
+addresses in the same row costs one row read and one row write, and a
+row change writes the cached row back before fetching the next.  An
+address at or past ``n_words`` is skipped, which is what the
+ragged-batch padding relies on (``ops`` pads with ``n_words``).
 
-``interpret=True`` is the CPU fallback path; for CPU *production*
-write-back the engine uses the numpy twin (``np_write_back`` below — a
-single fancy-index assignment, the same split as ``validate.py`` /
-``gather_read.py``); the kernel test pins the two element-for-element.
+For CPU write-back the engine uses the numpy twin (``np_write_back``
+below — a single fancy-index assignment); the kernel tests pin the two
+element-for-element in interpret mode.
 """
 from __future__ import annotations
 
@@ -30,6 +29,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+LANES = 128
 
 
 def np_write_back(heap: np.ndarray, addrs: np.ndarray,
@@ -52,35 +54,76 @@ def np_write_back(heap: np.ndarray, addrs: np.ndarray,
     return out
 
 
-def _scatter_kernel(heap_ref, addr_ref, val_ref, o_ref):
-    # constant-index output block: step 0 seeds it with the heap, every
-    # step scatters its (addr, val) tile into it; out-of-range pad
-    # addresses are dropped by scatter semantics
-    @pl.when(pl.program_id(0) == 0)
-    def _seed():
-        o_ref[...] = heap_ref[...]
+def scatter_rows(heap_hbm, row, sem, n_words, addr_ref, val_ref, keep):
+    """Write ``val_ref[i]`` at word ``addr_ref[i]`` of ``heap_hbm`` for
+    every ``i`` of the block where ``keep(i)`` holds and the address is
+    below ``n_words``.  Addresses must be ascending; ``row`` is a
+    ``[1, 128]`` VMEM scratch holding the current heap row.  Shared by
+    this kernel and the fused commit kernel."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
 
-    o_ref[...] = o_ref[...].at[addr_ref[...]].set(val_ref[...])
+    def copy_in(r):
+        cp = pltpu.make_async_copy(heap_hbm.at[pl.ds(r, 1)], row, sem)
+        cp.start()
+        cp.wait()
+
+    def copy_out(r):
+        cp = pltpu.make_async_copy(row, heap_hbm.at[pl.ds(r, 1)], sem)
+        cp.start()
+        cp.wait()
+
+    def body(i, cur):
+        a = addr_ref[i]
+        live = jnp.logical_and(a < n_words, keep(i))
+        r = a // LANES
+        switch = jnp.logical_and(live, r != cur)
+
+        @pl.when(jnp.logical_and(switch, cur >= 0))
+        def _():
+            copy_out(cur)
+
+        @pl.when(switch)
+        def _():
+            copy_in(r)
+
+        @pl.when(live)
+        def _():
+            row[...] = jnp.where(lane == a % LANES, val_ref[i], row[...])
+
+        return jnp.where(switch, r, cur)
+
+    cur = jax.lax.fori_loop(0, addr_ref.shape[0], body, jnp.int32(-1))
+
+    @pl.when(cur >= 0)
+    def _():
+        copy_out(cur)
 
 
-def scatter_write_flat(heap, addrs, values, *, tile: int = 512,
-                       interpret: bool = True):
-    """heap: [H]; addrs: [N] int32; values: [N] heap.dtype (N a multiple
-    of ``tile``).  Returns the [H] updated heap row.
+def _scatter_kernel(n_words, addr_ref, val_ref, heap_in, heap_hbm, row,
+                    sem):
+    del heap_in                      # aliased to heap_hbm
+    scatter_rows(heap_hbm, row, sem, n_words, addr_ref, val_ref,
+                 lambda i: True)
+
+
+def scatter_write_flat(heap, addrs, values, *, n_words: int,
+                       tile: int = 1024, interpret: bool = False):
+    """heap: [R, 128] rows of a 32-bit dtype; addrs: [N] int32 ascending;
+    values: [N] heap.dtype (N a multiple of ``tile``).  Addresses at or
+    past ``n_words`` are skipped.  Returns the updated [R, 128] heap.
     """
-    (h,) = heap.shape
     n = addrs.shape[0]
+    assert heap.ndim == 2 and heap.shape[1] == LANES, heap.shape
     assert n % tile == 0, (n, tile)
-    grid = (n // tile,)
+    smem = pl.BlockSpec((tile,), lambda i: (i,), memory_space=pltpu.SMEM)
     return pl.pallas_call(
-        _scatter_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((h,), lambda i: (0,)),
-            pl.BlockSpec((tile,), lambda i: (i,)),
-            pl.BlockSpec((tile,), lambda i: (i,)),
-        ],
-        out_specs=pl.BlockSpec((h,), lambda i: (0,)),
-        out_shape=jax.ShapeDtypeStruct((h,), heap.dtype),
+        lambda *refs: _scatter_kernel(n_words, *refs),
+        grid=(n // tile,),
+        in_specs=[smem, smem, pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        out_shape=jax.ShapeDtypeStruct(heap.shape, heap.dtype),
+        scratch_shapes=[pltpu.VMEM((1, LANES), heap.dtype),
+                        pltpu.SemaphoreType.DMA(())],
+        input_output_aliases={2: 0},
         interpret=interpret,
-    )(heap, addrs, values)
+    )(addrs, values, heap)

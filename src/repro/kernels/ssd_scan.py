@@ -62,7 +62,7 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, state_ref, *,
 
 
 def ssd_scan_pallas(xh, dt, A, B_, C_, *, chunk: int = 256,
-                    interpret: bool = True):
+                    interpret: bool = False):
     """xh: [B, S, H, P]; dt: [B, S, H]; A: [H]; B_, C_: [B, S, N].
 
     Returns (y [B, S, H, P], final_state [B, H, N, P]).

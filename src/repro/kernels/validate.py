@@ -19,11 +19,11 @@ Three predicates cover every lock-version backend (``mode`` scalar):
               (TinySTM exact-snapshot)
 
 Scalars ride in via ``PrefetchScalarGridSpec`` (SMEM), so one compiled
-kernel serves every (r_clock, tid, mode) triple.  ``interpret=True`` is
-the CPU fallback path; for CPU *production* validation the engine uses
-the numpy twin (``engine.validation.np_validate``) because interpret-mode
-tiling costs more than it saves — the kernel test pins the two
-implementations together element-for-element.
+kernel serves every (r_clock, tid, mode) triple.  The read set rides in
+lane-dense, as ``[N / 128, 128]`` int32 blocks of ``tile / 128`` rows.
+On CPU the engine uses the numpy twin (``engine.validation.np_validate``);
+the kernel tests pin the two together element-for-element in interpret
+mode.
 """
 from __future__ import annotations
 
@@ -35,50 +35,56 @@ from jax.experimental.pallas import tpu as pltpu
 #: padding element that every mode accepts: unlocked, unflagged,
 #: version -1 (< and <= any clock), seen -1 (== its own version)
 PAD = dict(ver=-1, own=-1, meta=0, seen=-1)
+#: lane width of the [N / 128, 128] layout the kernel reads
+LANES = 128
 
 
 def _validate_kernel(params_ref, ver_ref, own_ref, meta_ref, seen_ref,
                      o_ref):
+    # every predicate is an int32 0/1 vector: Mosaic cannot select
+    # between boolean vectors, so masks are widened as soon as they exist
+    def bit(cond):
+        return jnp.where(cond, 1, 0)
+
     r_clock = params_ref[0]
-    tid = params_ref[1]
     mode = params_ref[2]
     ver = ver_ref[...]
-    own = own_ref[...]
     meta = meta_ref[...]
-    seen = seen_ref[...]
-    locked = (meta & 1) != 0
-    flagged = (meta & 2) != 0
-    mine = jnp.logical_and(locked, own == tid)
-    free = jnp.logical_and(~locked, ~flagged)
-    unheld = jnp.logical_or(~locked, mine)
-    ok_lt = jnp.logical_or(mine, jnp.logical_and(free, ver < r_clock))
-    ok_le = jnp.logical_and(unheld, ver <= r_clock)
-    ok_eq = jnp.logical_and(unheld, ver == seen)
-    ok = jnp.where(mode == 0, ok_lt, jnp.where(mode == 1, ok_le, ok_eq))
-    o_ref[...] = ok.astype(jnp.int32)
+    locked = meta & 1
+    flagged = (meta >> 1) & 1
+    mine = locked & bit(own_ref[...] == params_ref[1])
+    free = (1 - locked) & (1 - flagged)
+    unheld = (1 - locked) | mine
+    ok_lt = mine | (free & bit(ver < r_clock))
+    ok_le = unheld & bit(ver <= r_clock)
+    ok_eq = unheld & bit(ver == seen_ref[...])
+    o_ref[...] = jnp.where(mode == 0, ok_lt,
+                           jnp.where(mode == 1, ok_le, ok_eq))
 
 
 def validate_readset_flat(ver, own, meta, seen, r_clock, tid, mode, *,
-                          tile: int = 512, interpret: bool = True):
-    """ver/own/meta/seen: [N] int32 (N a multiple of ``tile``).
+                          tile: int = 1024, interpret: bool = False):
+    """ver/own/meta/seen: [N / 128, 128] int32 (N a multiple of ``tile``,
+    ``tile`` a multiple of 1024).
 
-    Returns the [N] int32 validity mask (1 = entry still valid).  The
-    caller reduces with ``jnp.all`` — keeping the mask exposed lets
-    diagnostics name WHICH reads went stale, not just that one did.
+    Returns the [N / 128, 128] int32 validity mask (1 = entry still
+    valid).  The caller reduces with ``all`` — keeping the mask exposed
+    lets diagnostics name WHICH reads went stale, not just that one did.
     """
-    n = ver.shape[0]
-    assert n % tile == 0, (n, tile)
-    grid = (n // tile,)
-    spec = pl.BlockSpec((tile,), lambda i, params_ref: (i,))
+    rows, lanes = ver.shape
+    assert lanes == LANES and tile % (8 * LANES) == 0, (ver.shape, tile)
+    br = tile // LANES
+    assert rows % br == 0, (rows, br)
+    spec = pl.BlockSpec((br, LANES), lambda i, params_ref: (i, 0))
     params = jnp.asarray([r_clock, tid, mode], jnp.int32)
     return pl.pallas_call(
         _validate_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=grid,
+            grid=(rows // br,),
             in_specs=[spec, spec, spec, spec],
             out_specs=spec,
         ),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((rows, LANES), jnp.int32),
         interpret=interpret,
     )(params, ver, own, meta, seen)

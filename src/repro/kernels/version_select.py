@@ -18,10 +18,11 @@ Timestamps arrive REBASED to the reader's clock (the ``ops`` wrapper
 subtracts ``r_clock`` in int64 and clips to int32 — same treatment as
 ``kernels/validate.py``), so the predicate inside is ``ts < 0`` with the
 clock scalar pinned to 0; empty slots carry the positive-saturated
-sentinel and fail it naturally.  ``interpret=True`` is the CPU fallback
-path; for CPU *production* reads the engine uses the numpy twin
-(``core.vlt.np_version_select``) per the validate.py / gather_read.py
-pattern — the kernel test pins the two element-for-element.
+sentinel and fail it naturally.  The batch rides in slot-major and
+lane-dense (``[D, N / 128, 128]``), so each slot is one vector and the
+select is a chain of int32 ``where``s.  On CPU the engine uses the numpy
+twin (``core.vlt.np_version_select``); the kernel tests pin the two
+element-for-element in interpret mode.
 """
 from __future__ import annotations
 
@@ -33,41 +34,51 @@ from jax.experimental.pallas import tpu as pltpu
 #: rebased-timestamp padding for ragged batches: positive-saturated, so
 #: the ``ts < clock`` predicate rejects it for every clock value
 PAD_TS = (1 << 31) - 1
+#: lane width of the slot-major [D, N / 128, 128] layout
+LANES = 128
 
 
 def _select_kernel(params_ref, ts_ref, data_ref, val_ref, ok_ref):
+    # walk the depth oldest-first so the newest qualifying slot is the
+    # last one written: plain int32 selects, no argmax/any reductions
     clock = params_ref[0]
-    valid = ts_ref[...] < clock            # [tile, D], newest-first rows
-    first = jnp.argmax(valid, axis=1)      # first True == newest valid
-    val = jnp.take_along_axis(data_ref[...], first[:, None], axis=1)
-    val_ref[...] = val[:, 0]
-    ok_ref[...] = jnp.any(valid, axis=1).astype(jnp.int32)
+    val = jnp.zeros(val_ref.shape, val_ref.dtype)
+    ok = jnp.zeros(ok_ref.shape, jnp.int32)
+    for j in reversed(range(ts_ref.shape[0])):
+        valid = ts_ref[j] < clock
+        val = jnp.where(valid, data_ref[j], val)
+        ok = jnp.where(valid, 1, ok)
+    val_ref[...] = val
+    ok_ref[...] = ok
 
 
-def version_select_flat(ts, data, clock, *, tile: int = 256,
-                        interpret: bool = True):
-    """ts: [N, D] int32 (rebased); data: [N, D]; clock: int32 scalar.
+def version_select_flat(ts, data, clock, *, tile: int = 1024,
+                        interpret: bool = False):
+    """ts: [D, N / 128, 128] int32 (rebased); data: [D, N / 128, 128]
+    (32-bit); clock: int32 scalar.  Slot ``j`` of row ``n`` sits at
+    ``[j, n // 128, n % 128]``, slot 0 newest.
 
-    Returns ``(values [N] data.dtype, ok [N] int32)``: per row, the
+    Returns ``(values, ok)``, both ``[N / 128, 128]``: per row, the
     newest ``data`` whose ``ts`` is strictly below ``clock``, and
     whether any slot qualified (``values`` is only meaningful where
     ``ok``).  Rows are tiled over the grid; ``D`` rides whole.
     """
-    n, depth = ts.shape
-    assert n % tile == 0, (n, tile)
-    grid = (n // tile,)
-    row2d = pl.BlockSpec((tile, depth), lambda i, params_ref: (i, 0))
-    row1d = pl.BlockSpec((tile,), lambda i, params_ref: (i,))
+    depth, rows, lanes = ts.shape
+    assert lanes == LANES and tile % (8 * LANES) == 0, (ts.shape, tile)
+    br = tile // LANES
+    assert rows % br == 0, (rows, br)
+    slots = pl.BlockSpec((depth, br, LANES), lambda i, params_ref: (0, i, 0))
+    flat = pl.BlockSpec((br, LANES), lambda i, params_ref: (i, 0))
     params = jnp.asarray([clock], jnp.int32)
     return pl.pallas_call(
         _select_kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[row2d, row2d],
-            out_specs=[row1d, row1d],
+            grid=(rows // br,),
+            in_specs=[slots, slots],
+            out_specs=[flat, flat],
         ),
-        out_shape=[jax.ShapeDtypeStruct((n,), data.dtype),
-                   jax.ShapeDtypeStruct((n,), jnp.int32)],
+        out_shape=[jax.ShapeDtypeStruct((rows, LANES), data.dtype),
+                   jax.ShapeDtypeStruct((rows, LANES), jnp.int32)],
         interpret=interpret,
     )(params, ts, data)

@@ -151,8 +151,6 @@ _NUM_KEYS = ("flops", "bytes", "tpu_bytes", "wire_bytes",
 
 def _metrics(compiled):
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):     # jax<=0.4.x: one dict per device
-        cost = cost[0]
     try:
         text = compiled.as_text()
     except Exception:

@@ -9,27 +9,20 @@ from __future__ import annotations
 import jax
 
 
-def _axis_types(n: int):
-    """jax.sharding.AxisType landed in jax 0.4.35; older jax infers Auto."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     """Single pod: 16x16 (data, model).  Multi-pod: 2x16x16 (pod, data,
     model) — the 'pod' axis is DP by default and the pipeline axis when
     ``ParallelConfig.pipeline_stages > 1``."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_types(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
     """Arbitrary mesh (tests / small-scale runs)."""
-    return jax.make_mesh(tuple(shape), tuple(axes),
-                         **_axis_types(len(axes)))
+    return jax.make_mesh(
+        tuple(shape), tuple(axes),
+        axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh():

@@ -1,9 +1,7 @@
 """Roofline terms from a compiled dry-run artifact.
 
-Hardware model (TPU v5e target, per assignment):
-  peak bf16 compute   197 TFLOP/s / chip
-  HBM bandwidth       819 GB/s / chip
-  ICI bandwidth       ~50 GB/s / link / chip
+Hardware model: the per-chip peaks in ``PEAKS``, keyed by the device
+kind JAX reports; the dry-run targets ``DRYRUN_DEVICE_KIND`` (TPU v5e).
 
 cost_analysis() of the SPMD-partitioned executable reports *per-device*
 flops and bytes.  Collective bytes are NOT in cost_analysis: we parse the
@@ -18,9 +16,24 @@ from __future__ import annotations
 import re
 from typing import Dict
 
-PEAK_FLOPS = 197e12      # bf16 / chip
-HBM_BW = 819e9           # B/s / chip
-ICI_BW = 50e9            # B/s / link
+#: Per-chip peaks keyed by ``jax.Device.device_kind``.  Source: Google
+#: Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s of HBM
+#: bandwidth and 1,600 Gbit/s of interconnect per chip, i.e. 50 GB/s on
+#: each of its four ICI links.
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+#: the chip the dry-run models
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """The ``PEAKS`` row for ``device_kind``; a device missing from the
+    table is an error, never a default."""
+    if device_kind not in PEAKS:
+        raise KeyError(f"no peak figures for device kind {device_kind!r}; "
+                       f"known: {sorted(PEAKS)}")
+    return PEAKS[device_kind]
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -257,19 +270,21 @@ def attention_score_bytes(compiled_or_text, block_q: int = 1024,
 
 
 def roofline_terms(cfg, shape, *, cost: Dict, collectives: Dict,
-                   n_chips: int) -> Dict:
+                   n_chips: int,
+                   device_kind: str = DRYRUN_DEVICE_KIND) -> Dict:
     """The three terms (seconds) + MODEL_FLOPS ratio for one cell."""
     from repro.models.model_zoo import model_flops
 
+    peak = peaks(device_kind)
     flops_dev = float(cost.get("flops") or 0.0)
     bytes_dev = float(cost.get("bytes accessed") or 0.0)
     wire_dev = float(collectives.get("total_wire_bytes") or 0.0)
-    t_compute = flops_dev / PEAK_FLOPS
-    t_memory = bytes_dev / HBM_BW
+    t_compute = flops_dev / peak["flops"]
+    t_memory = bytes_dev / peak["hbm_bw"]
     # per assignment: collective_bytes / (chips * link_bw), with
     # collective_bytes global = per-device wire * chips -> simplifies to
     # per-device wire / link_bw
-    t_coll = wire_dev / ICI_BW
+    t_coll = wire_dev / peak["ici_bw"]
     mf = model_flops(cfg, shape)
     hlo_global = flops_dev * n_chips
     dominant = max((("compute", t_compute), ("memory", t_memory),
@@ -284,5 +299,5 @@ def roofline_terms(cfg, shape, *, cost: Dict, collectives: Dict,
         "hlo_flops_global": hlo_global,
         "useful_flops_ratio": (mf / hlo_global) if hlo_global else 0.0,
         "roofline_fraction": (
-            (mf / (n_chips * PEAK_FLOPS)) / total if total else 0.0),
+            (mf / (n_chips * peak["flops"])) / total if total else 0.0),
     }

@@ -43,6 +43,7 @@ from repro.launch import steps as steps_mod
 from repro.launch.mesh import make_host_mesh
 from repro.launch.sharding import default_rules, use_rules
 from repro.models import model_zoo as zoo
+from repro.runtime.compile_cache import use_compile_cache
 from repro.serve.metrics import ServeMetrics
 from repro.serve.queue import Outcome, Request, RequestQueue
 from repro.serve.scheduler import ContinuousBatchingScheduler, StepResult
@@ -277,6 +278,7 @@ def main(argv=None):
     ap.add_argument("--prompt-len", type=int, default=32)
     ap.add_argument("--gen", type=int, default=16)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     if cfg.frontend != "none" or cfg.is_encdec:

@@ -11,10 +11,12 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import dataclasses
+import functools
 from dataclasses import dataclass, field
 from typing import Any, Optional, Sequence, Tuple
 
 import jax
+import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 # ---------------------------------------------------------------------------
@@ -135,6 +137,13 @@ class ParamMeta:
         assert len(self.shape) == len(self.axes), (self.shape, self.axes)
 
 
+@functools.partial(jax.jit, static_argnames=("shape", "dtype", "std"))
+def _scaled_normal(key, *, shape, dtype, std):
+    # one fused program: only the ``dtype`` result is ever allocated (run
+    # eagerly, a full-width leaf held two float32 copies at its peak)
+    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
+
+
 def materialize(meta_tree, key, dtype_override: Optional[str] = None):
     """Turn a tree of ParamMeta into concrete initialized arrays."""
     import jax.numpy as jnp
@@ -152,7 +161,7 @@ def materialize(meta_tree, key, dtype_override: Optional[str] = None):
         else:
             fan_in = m.shape[-2] if len(m.shape) >= 2 else m.shape[-1]
             std = m.scale / max(fan_in, 1) ** 0.5
-            a = (jax.random.normal(k, m.shape, jnp.float32) * std).astype(dt)
+            a = _scaled_normal(k, shape=tuple(m.shape), dtype=dt, std=std)
         out.append(a)
     return jax.tree.unflatten(treedef, out)
 

@@ -35,6 +35,7 @@ from repro.launch.mesh import make_host_mesh
 from repro.launch.sharding import default_rules, use_rules
 from repro.models import model_zoo as zoo
 from repro.optim import adamw
+from repro.runtime.compile_cache import use_compile_cache
 from repro.runtime.fault_tolerance import FaultPlan, TrainSupervisor
 
 
@@ -109,6 +110,7 @@ def main(argv=None):
     ap.add_argument("--mv-mode", default="Q", choices=["Q", "U"])
     ap.add_argument("--inject-failure-at", type=int, default=-1)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     shape = ShapeConfig("train_cli", args.seq, args.batch, "train")

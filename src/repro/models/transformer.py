@@ -23,17 +23,6 @@ from repro.models.common import rmsnorm, rmsnorm_meta, softmax_xent
 VOCAB_PAD_MULTIPLE = 256
 
 
-def _shard_map(f, *, mesh, in_specs, out_specs):
-    """jax.shard_map (>=0.5) vs jax.experimental.shard_map: on the older
-    API, skip replication checking the same way check_vma=False does."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
-
-
 def layer_period(cfg: ModelConfig) -> int:
     if cfg.family == "ssm":
         return 1
@@ -107,11 +96,12 @@ def embed_lookup(table, tokens, pcfg: ParallelConfig):
                 tbl = jax.lax.all_gather(tbl, fsdp_ax, axis=0, tiled=True)
             return jnp.take(tbl, tok, axis=0)
 
-        h = _shard_map(
+        h = jax.shard_map(
             body, mesh=mesh,
             in_specs=(rules.spec(("fsdp", "tp")),
                       rules.spec(("batch", None))),
-            out_specs=rules.spec(("batch", None, "tp")))(table, tokens)
+            out_specs=rules.spec(("batch", None, "tp")),
+            check_vma=False)(table, tokens)
     else:
         h = jnp.take(table, tokens, axis=0)
     return shard_act(h, ("batch", None, None))

@@ -13,3 +13,25 @@ import pytest  # noqa: E402
 def rng_key():
     import jax
     return jax.random.PRNGKey(0)
+
+
+#: the TM kernel wrappers the engine dispatches to on TPU
+TM_WRAPPERS = ("snapshot_read", "write_back", "publish_row", "commit_fused",
+               "validate_readset", "version_select", "snapshot_select")
+
+
+@pytest.fixture
+def kernel_branch(monkeypatch):
+    """Steer the engine onto its TPU branch on CPU: ``ops.on_tpu()``
+    reports True and every TM kernel wrapper runs its kernel in the
+    Pallas interpreter.  Yields ``ops.COUNTS``, reset at entry."""
+    import functools
+
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "on_tpu", lambda: True)
+    for name in TM_WRAPPERS:
+        monkeypatch.setattr(ops, name, functools.partial(
+            getattr(ops, name), interpret=True))
+    ops.COUNTS.reset()
+    yield ops.COUNTS
+    ops.COUNTS.reset()

@@ -48,20 +48,22 @@ def test_scatter_kernel_matches_numpy_twin():
     from repro.kernels import scatter_write as SW
 
     rng = np.random.default_rng(7)
-    for h, n in ((64, 16), (512, 512), (1000, 128)):
+    for h, n in ((64, 16), (512, 512), (1000, 128), (3000, 2000)):
         heap = rng.integers(-100, 100, size=h).astype(np.int32)
         addrs = rng.choice(h, size=n, replace=False).astype(np.int32)
         vals = rng.integers(-100, 100, size=n).astype(np.int32)
         want = SW.np_write_back(heap, addrs, vals)
-        tile = min(512, 1 << (n - 1).bit_length()) if n > 1 else 1
+        # the kernel's contract: [R, 128] rows, ascending addresses,
+        # batch padded to whole tiles with an address it skips (h)
+        tile = 1024
+        order = np.argsort(addrs)
         pad = (-n) % tile
-        a, v = addrs, vals
-        if pad:
-            a = np.pad(addrs, (0, pad), constant_values=h)  # dropped
-            v = np.pad(vals, (0, pad))
-        got = np.asarray(SW.scatter_write_flat(heap, a, v, tile=tile,
-                                               interpret=True))
-        np.testing.assert_array_equal(got, want)
+        a = np.pad(addrs[order], (0, pad), constant_values=h)
+        v = np.pad(vals[order], (0, pad))
+        rows = np.pad(heap, (0, (-h) % SW.LANES)).reshape(-1, SW.LANES)
+        got = np.asarray(SW.scatter_write_flat(
+            rows, a, v, n_words=h, tile=tile, interpret=True))
+        np.testing.assert_array_equal(got.reshape(-1)[:h], want)
 
 
 def test_ops_write_back_pads_ragged_batches():
@@ -73,12 +75,13 @@ def test_ops_write_back_pads_ragged_batches():
     for n in (1, 7, 63, 300):
         addrs = rng.choice(300, size=n, replace=False)
         vals = rng.integers(0, 100, size=n).astype(np.int64)
-        got = ops.write_back(heap, addrs, vals)
+        got = ops.write_back(heap, addrs, vals, interpret=True)
         np.testing.assert_array_equal(got, np_write_back(heap, addrs,
                                                          vals))
     # empty batch: unchanged copy
     np.testing.assert_array_equal(
-        ops.write_back(heap, np.zeros(0, np.int64), np.zeros(0, np.int64)),
+        ops.write_back(heap, np.zeros(0, np.int64), np.zeros(0, np.int64),
+                       interpret=True),
         heap)
 
 

@@ -108,25 +108,28 @@ def test_fused_kernel_matches_numpy_twin(mode):
             heap, w_flat, w_val, w_seg, l_ver, l_own, l_meta, l_seg,
             r_ver, r_own, r_meta, r_seen, r_seg, tids, rcs,
             cv, n_txn, mode)
-        # pad the write batch to a tile multiple; pad addrs point
-        # one-past-the-end (dropped), pad segs at a passing slot is
-        # irrelevant since the address is out of range either way
-        tile = 8
+        # the kernel's contract: [R, 128] heap rows, the write batch
+        # sorted by address and padded to a whole tile with an address
+        # it skips (h); pad segs at a passing slot are irrelevant
+        tile = 1024
+        order = np.argsort(w_flat, kind="stable")
         pad = (-w_flat.size) % tile or tile
-        a = np.concatenate([w_flat, np.full(pad, h, np.int64)])
-        v = np.concatenate([w_val, np.zeros(pad, np.int64)])
-        s = np.concatenate([w_seg, np.zeros(pad, np.int64)])
+        a = np.concatenate([w_flat[order], np.full(pad, h, np.int64)])
+        v = np.concatenate([w_val[order], np.zeros(pad, np.int64)])
+        s = np.concatenate([w_seg[order], np.zeros(pad, np.int64)])
 
         def i32(x):
             return np.asarray(x, np.int32)
 
+        rows = np.pad(heap, (0, (-h) % CF.LANES)).reshape(-1, CF.LANES)
         got_heap, got_ok, got_lver = CF.commit_fused_flat(
-            heap, i32(a), i32(v), i32(s),
+            rows, i32(a), i32(v), i32(s),
             i32(l_ver), l_own, l_meta, i32(l_seg),
             i32(r_ver), r_own, r_meta, i32(r_seen), i32(r_seg),
             i32(tids), i32(rcs), np.array([cv], np.int32),
-            mode=mode, tile=tile, interpret=True)
-        np.testing.assert_array_equal(np.asarray(got_heap), want_heap)
+            n_words=h, mode=mode, tile=tile, interpret=True)
+        np.testing.assert_array_equal(
+            np.asarray(got_heap).reshape(-1)[:h], want_heap)
         np.testing.assert_array_equal(np.asarray(got_ok) != 0, want_ok)
         np.testing.assert_array_equal(np.asarray(got_lver),
                                       want_lver.astype(np.int32))

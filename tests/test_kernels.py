@@ -21,7 +21,8 @@ def test_flash_attention_sweep(B, S, H, KV, D, causal, dtype):
     q = jax.random.normal(ks[0], (B, S, H, D), jnp.float32).astype(dtype)
     k = jax.random.normal(ks[1], (B, S, KV, D), jnp.float32).astype(dtype)
     v = jax.random.normal(ks[2], (B, S, KV, D), jnp.float32).astype(dtype)
-    o = ops.flash_attention(q, k, v, causal=causal, block_q=64, block_k=64)
+    o = ops.flash_attention(q, k, v, causal=causal, block_q=64, block_k=64,
+                            interpret=True)
     G = H // KV
     qr = q.transpose(0, 2, 1, 3).reshape(B * H, S, D)
     kr = jnp.repeat(k.transpose(0, 2, 1, 3), G, 1).reshape(B * H, S, D)
@@ -43,7 +44,8 @@ def test_flash_attention_matches_blockwise_xla():
     q = jax.random.normal(ks[0], (B, S, H, D), jnp.float32)
     k = jax.random.normal(ks[1], (B, S, KV, D), jnp.float32)
     v = jax.random.normal(ks[2], (B, S, KV, D), jnp.float32)
-    o1 = ops.flash_attention(q, k, v, causal=True, block_q=64, block_k=64)
+    o1 = ops.flash_attention(q, k, v, causal=True, block_q=64, block_k=64,
+                             interpret=True)
     o2 = blockwise_attention(q, k, v, causal=True, block_q=64, block_k=64)
     np.testing.assert_allclose(np.asarray(o1), np.asarray(o2),
                                rtol=2e-4, atol=2e-4)
@@ -70,7 +72,7 @@ def test_ssd_scan_sweep(B, S, H, P, N, chunk, dtype):
           ).astype(dtype)
     C_ = (jax.random.normal(ks[4], (B, S, N), jnp.float32) * 0.5
           ).astype(dtype)
-    y, _ = ops.ssd_scan(xh, dt, A, B_, C_, chunk=chunk)
+    y, _ = ops.ssd_scan(xh, dt, A, B_, C_, chunk=chunk, interpret=True)
     yr, _ = ref.ssd_scan_ref(xh, dt, A, B_, C_)
     tol = 5e-2 if dtype == jnp.bfloat16 else 2e-3
     np.testing.assert_allclose(np.asarray(y, np.float32),
@@ -109,7 +111,8 @@ def test_snapshot_select_sweep(R, shape, dtype):
     ts = jnp.asarray(np.random.RandomState(0).permutation(R) * 3 - 1,
                      jnp.int32)
     for clock in (-1, 0, 2, 5, 100):
-        val, ok = ops.snapshot_select(ring, ts, jnp.int32(clock))
+        val, ok = ops.snapshot_select(ring, ts, jnp.int32(clock),
+                                      interpret=True)
         vr, okr = ref.snapshot_select_ref(
             ring.reshape(R, -1), ts, clock)
         assert bool(ok) == bool(okr)
@@ -132,7 +135,8 @@ def test_fused_adamw_sweep(shape, with_ring, dtype):
     kw = dict(lr=jnp.float32(3e-3), scale=jnp.float32(0.7), b1=0.9,
               b2=0.95, eps=1e-8, wd=0.1)
     p2, m2, v2, r2 = ops.fused_adamw(p, g, m, v, ring, 2,
-                                     count=jnp.int32(3), **kw)
+                                     count=jnp.int32(3), interpret=True,
+                                     **kw)
     cnt = jnp.float32(3)
     pr, mr, vr2, rr = ref.fused_adamw_ref(
         p.reshape(-1), g.reshape(-1), m.reshape(-1), v.reshape(-1),
@@ -181,7 +185,7 @@ def test_validate_readset_kernel_matches_scalar(mode, n):
         scalar = V.revalidate_scalar(lt, read_set, r_clock, tid, mode)
         via_np = V.np_validate(ver, own, meta, seen, r_clock, tid, mode)
         via_kernel = ops.validate_readset(ver, own, meta, seen, r_clock,
-                                          tid, mode)
+                                          tid, mode, interpret=True)
         assert scalar == via_np == via_kernel, (mode, n, r_clock, tid)
 
 
@@ -204,17 +208,20 @@ def test_validate_readset_kernel_elementwise_mask():
                         for s in states], jnp.int32)
     seen = jnp.asarray([s.version if i % 2 == 0 else s.version + 1
                         for i, s in enumerate(states)], jnp.int32)
-    pad = (-len(states)) % 8
+    # the kernel's contract: [N / 128, 128] int32, one 1024-entry tile
+    pad = (-len(states)) % 1024
     pd = vk.PAD
 
     def prep(x, fill):
-        return jnp.pad(x, (0, pad), constant_values=fill)
+        return jnp.pad(x, (0, pad), constant_values=fill).reshape(
+            -1, vk.LANES)
 
     for mode in (0, 1, 2):
         mask = vk.validate_readset_flat(
             prep(ver, pd["ver"]), prep(own, pd["own"]),
             prep(meta, pd["meta"]), prep(seen, pd["seen"]),
-            r_clock=5, tid=0, mode=mode, tile=8, interpret=True)
+            r_clock=5, tid=0, mode=mode, tile=1024,
+            interpret=True).reshape(-1)
         for i, s in enumerate(states):
             want = V.check_entry(s, int(seen[i]), 5, 0, mode)
             assert bool(mask[i]) == want, (mode, i, s)
@@ -234,9 +241,61 @@ def test_validate_readset_survives_64bit_clock():
     seen = ver.copy()
     for mode, r_clock in [(0, big), (0, big + 2), (1, big), (2, big + 2)]:
         want = V.np_validate(ver, own, meta, seen, r_clock, 0, mode)
-        got = ops.validate_readset(ver, own, meta, seen, r_clock, 0, mode)
+        got = ops.validate_readset(ver, own, meta, seen, r_clock, 0, mode,
+                                   interpret=True)
         assert got == want, (mode, r_clock, got, want)
     # stale entry at a 64-bit clock: version == r_clock fails V_LT
     assert not ops.validate_readset(
         np.asarray([big], np.int64), own[:1], meta[:1],
-        np.asarray([big], np.int64), big, 0, 0)
+        np.asarray([big], np.int64), big, 0, 0, interpret=True)
+
+
+# ---------------------------------------------------------------------------
+# TM wrappers vs their numpy twins at the chip tiling (interpret mode):
+# ragged batches below one (8, 128) tile, just past it and over several
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("n", [1, 1025, 3000])
+def test_tm_wrappers_match_twins_at_chip_tiles(n):
+    from repro.core.vlt import np_version_select
+    from repro.kernels import commit_fused as cf
+    from repro.kernels.scatter_write import np_write_back
+
+    rng = np.random.default_rng(n)
+    h = 5000 + n                       # not a whole number of rows
+    heap = rng.integers(-1000, 1000, h).astype(np.int64)
+    a = rng.integers(0, h, n)
+    np.testing.assert_array_equal(
+        np.asarray(ops.snapshot_read(heap, a, interpret=True)), heap[a])
+    au = rng.choice(h, n, replace=False)
+    v = rng.integers(-50, 50, n)
+    want = np_write_back(heap, au, v)
+    np.testing.assert_array_equal(
+        ops.write_back(heap, au, v, interpret=True), want)
+    np.testing.assert_array_equal(np.asarray(ops.publish_row(
+        jnp.asarray(heap, jnp.int32), au, v, interpret=True)), want)
+    ts = rng.integers(0, 20, (n, 4))
+    data = rng.integers(-99, 99, (n, 4))
+    for clock in (0, 7, 21):
+        gv, go = ops.version_select(ts, data, clock, interpret=True)
+        wv, wo = np_version_select(ts, data, clock)
+        np.testing.assert_array_equal(go, wo)
+        np.testing.assert_array_equal(gv[wo], wv[wo])
+    # one member per write, every other member's single lock held by a
+    # foreign owner: the fused verdict and scatter against the twin
+    seg = np.arange(n)
+    from repro.core.engine.arrayheap import pack_lock
+    from repro.core.locks import LockState
+    l_words = np.array([pack_lock(LockState(t % 2 == 1, 3, n + 7, False))
+                        for t in range(n)], np.int64)
+    z = np.zeros((0,), np.int64)
+    got = ops.commit_fused(jnp.asarray(heap, jnp.int32), au, v, seg,
+                           l_words, seg, z, z, z, seg, np.full(n, 9), 10,
+                           n, mode=cf.MODE_LE, interpret=True)
+    ref_ = ops.commit_fused(heap, au, v, seg, l_words, seg, z, z, z, seg,
+                            np.full(n, 9), 10, n, mode=cf.MODE_LE)
+    np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(ref_[0]))
+    np.testing.assert_array_equal(got[1], ref_[1])
+    np.testing.assert_array_equal(got[2], ref_[2])
+    assert got[1].tolist() == [t % 2 == 0 for t in range(n)]

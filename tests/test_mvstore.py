@@ -97,7 +97,7 @@ def test_unversion_blocks_drops_rings():
     assert mvstore.ring_bytes(st) == 0
 
 
-def test_snapshot_pallas_path_matches_xla():
+def test_snapshot_pallas_path_matches_xla(kernel_branch):
     cfg = MVStoreConfig(ring_slots=4, mode="U")
     st = mvstore.mv_init(params_tree(), cfg, versioned="all")
     for i in range(3):
@@ -108,6 +108,7 @@ def test_snapshot_pallas_path_matches_xla():
     assert bool(ok1) == bool(ok2)
     for a, b in zip(jax.tree.leaves(v1), jax.tree.leaves(v2)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert kernel_branch.entries["snapshot_select"] > 0
 
 
 def test_controller_full_mode_cycle():

@@ -62,6 +62,11 @@ def test_roofline_terms_identifies_dominant():
     assert terms["dominant"] == "compute"
     assert terms["t_compute_s"] == pytest.approx(1e14 / 197e12)
     assert 0 < terms["roofline_fraction"] <= 1.5
+    # peaks are keyed by device kind: an unknown device is an error
+    with pytest.raises(KeyError):
+        roofline.roofline_terms(
+            cfg, shape, cost={"flops": 1.0}, collectives={}, n_chips=1,
+            device_kind="cpu")
 
 
 @pytest.mark.slow

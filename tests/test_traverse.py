@@ -258,18 +258,21 @@ def test_version_select_kernel_matches_numpy_twin():
         for clock in (1, 500, 999):
             want_v, want_ok = np_version_select(ts, data, clock)
             rel = np.clip(ts - clock, -(1 << 31) + 1, (1 << 31) - 1)
-            tile = min(256, 1 << (n - 1).bit_length()) if n > 1 else 1
+            # the kernel's contract: slot-major [D, N / 128, 128],
+            # ragged batches padded to a whole 1024-row tile
+            tile = 1024
             pad = (-n) % tile
-            relj = jnp.asarray(rel, jnp.int32)
-            dj = jnp.asarray(data)
-            if pad:
-                relj = jnp.pad(relj, ((0, pad), (0, 0)),
-                               constant_values=VS.PAD_TS)
-                dj = jnp.pad(dj, ((0, pad), (0, 0)))
-            got_v, got_ok = VS.version_select_flat(relj, dj, 0, tile=tile,
-                                                  interpret=True)
-            got_v = np.asarray(got_v)[:n]
-            got_ok = np.asarray(got_ok)[:n] != 0
+            relj = jnp.pad(jnp.asarray(rel, jnp.int32), ((0, pad), (0, 0)),
+                           constant_values=VS.PAD_TS)
+            dj = jnp.pad(jnp.asarray(data, jnp.int32), ((0, pad), (0, 0)))
+
+            def slots(x):
+                return x.T.reshape(x.shape[1], -1, VS.LANES)
+
+            got_v, got_ok = VS.version_select_flat(
+                slots(relj), slots(dj), 0, tile=tile, interpret=True)
+            got_v = np.asarray(got_v).reshape(-1)[:n]
+            got_ok = np.asarray(got_ok).reshape(-1)[:n] != 0
             np.testing.assert_array_equal(want_ok, got_ok)
             np.testing.assert_array_equal(want_v[want_ok], got_v[got_ok])
 
@@ -281,7 +284,7 @@ def test_ops_version_select_pads_ragged_batches():
     for n in (1, 7, 63, 300):
         ts = rng.integers(0, 100, size=(n, 4)).astype(np.int64)
         data = rng.integers(0, 100, size=(n, 4)).astype(np.int64)
-        vals, ok = ops.version_select(ts, data, 50)
+        vals, ok = ops.version_select(ts, data, 50, interpret=True)
         want_v, want_ok = np_version_select(ts, data, 50)
         np.testing.assert_array_equal(ok, want_ok)
         np.testing.assert_array_equal(vals[ok], want_v[want_ok])
