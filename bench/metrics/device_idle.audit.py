@@ -1,0 +1,9 @@
+"""Percent of the traced window in which no operation ran on the device,
+in a cell with audits (device busy is the union of op intervals)."""
+
+
+def read(rec):
+    t = rec.trace
+    if not t or t["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])
