@@ -252,48 +252,6 @@ def bench_mvstore():
 
 
 # ---------------------------------------------------------------------------
-# Kernel microbenches (the platform's path: the compiled kernel on TPU,
-# the jnp reference elsewhere — a CPU timing times the reference)
-# ---------------------------------------------------------------------------
-
-
-def bench_kernels():
-    import jax
-    import jax.numpy as jnp
-    from repro.kernels import ops
-
-    rows = []
-    key = jax.random.PRNGKey(0)
-    B, S, H, KV, D = 1, 256, 4, 2, 64
-    ks = jax.random.split(key, 3)
-    q = jax.random.normal(ks[0], (B, S, H, D), jnp.float32)
-    k = jax.random.normal(ks[1], (B, S, KV, D), jnp.float32)
-    v = jax.random.normal(ks[2], (B, S, KV, D), jnp.float32)
-
-    def timeit(fn, n=3):
-        fn()
-        t0 = time.time()
-        for _ in range(n):
-            jax.block_until_ready(fn())
-        return (time.time() - t0) / n
-
-    t = timeit(lambda: ops.flash_attention(q, k, v, causal=True,
-                                           block_q=64, block_k=64))
-    plat = jax.default_backend()
-    _emit("kernels/flash_attention", t * 1e6,
-          f"S={S};H={H};D={D};platform={plat}")
-    rows.append({"kernel": "flash_attention", "seconds": t})
-
-    ring = jax.random.normal(key, (4, 1024, 64), jnp.float32)
-    ts = jnp.asarray([1, 5, 3, -1], jnp.int32)
-    t = timeit(lambda: ops.snapshot_select(ring, ts, jnp.int32(4)))
-    _emit("kernels/snapshot_select", t * 1e6, f"R=4;n=64k;platform={plat}")
-    rows.append({"kernel": "snapshot_select", "seconds": t})
-    _save("kernels", rows)
-    return rows
-
-
-# ---------------------------------------------------------------------------
 # Group commit + rwmix headline (PR 7) — persisted under bench_*.json so CI
 # leaves both artifacts in the shared results schema
 # ---------------------------------------------------------------------------
@@ -405,40 +363,17 @@ def bench_durability():
     return rows
 
 
-# ---------------------------------------------------------------------------
-# Roofline report (reads the dry-run sweep results)
-# ---------------------------------------------------------------------------
-
-
-def bench_roofline_report():
-    from benchmarks.roofline_report import render
-    fit = os.path.join(RESULTS_DIR, "dryrun_fit.jsonl")
-    probes = os.path.join(RESULTS_DIR, "dryrun_probes.jsonl")
-    if not os.path.exists(fit):
-        _emit("roofline/skipped", 0.0, "no dry-run results found")
-        return []
-    rows = render(fit, probes if os.path.exists(probes) else None)
-    for r in rows:
-        if r.get("roofline_fraction") is not None:
-            _emit(f"roofline/{r['arch']}/{r['shape']}", 0.0,
-                  f"dominant={r.get('dominant')};"
-                  f"frac={r['roofline_fraction']:.3f}")
-    return rows
-
-
 BENCHES = {
     "fig6": bench_fig6_throughput,
     "appendix": bench_appendix_structs,
     "fig8": bench_fig8_timevarying,
     "fig9": bench_fig9_memory,
     "mvstore": bench_mvstore,
-    "kernels": bench_kernels,
     "groupcommit": bench_groupcommit,
     "rwmix": bench_rwmix,
     "shardscale": bench_shardscale,
     "reliability": bench_reliability,
     "durability": bench_durability,
-    "roofline": bench_roofline_report,
 }
 
 

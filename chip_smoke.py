@@ -16,6 +16,8 @@ Phases, each printing one ``phase <name> {...}`` line of its numbers
            burst on ``tl2`` and Mode-U ``mvstore`` commits; every
            completed scan must see the conserved sum, every device site
            must be entered and no batch may take the int64 twin route;
+           prints the words each ``read_bulk`` tier resolved and the
+           host bytes uploaded and copied back per kernel;
   serve    the snapshot server at full ``qwen2.5-3b`` width (random
            weights from a seed) in Mode Q, with store commits landing
            between requests;
@@ -336,12 +338,17 @@ def tm_phase(*, heap_words: int = 1 << 22, region: int = 1 << 20,
     final = int(np.asarray(tm.raw.heap.gather(
         np.arange(lo, lo + region))).sum())
     stats = tm.stats()
+    raw = tm.raw.stats()
     tm.stop()
     assert counts["violations"] == 0, counts
     assert counts["scans"] >= 1, counts
     assert final == expected, (final, expected)
+    # words the scans' read_bulk tiers resolved: batch, mirror, scalar
+    tiers = {k: raw[k] for k in ("bulk_batch_words", "version_gather_hits",
+                                 "bulk_scalar_words")}
     out["scan"] = dict(counts, setup_s=t_setup, run_s=t_run,
-                       mode_transitions=stats.get("mode_transitions", 0))
+                       mode_transitions=stats.get("mode_transitions", 0),
+                       **tiers)
 
     # -- CommitBatcher burst (tl2): groups through ops.commit_fused ------
     g = make_tm("tl2", group_size, params=params, array_heap=True)
@@ -411,6 +418,8 @@ def tm_phase(*, heap_words: int = 1 << 22, region: int = 1 << 20,
 
     out["site_entries"] = dict(ops.COUNTS.entries)
     out["int64_twin_routes"] = dict(ops.COUNTS.twin_routes)
+    out["h2d_bytes"] = dict(ops.COUNTS.h2d_bytes)
+    out["d2h_bytes"] = dict(ops.COUNTS.d2h_bytes)
     for site in ("gather_read", "validate", "version_select",
                  "commit_fused"):
         assert ops.COUNTS.entries[site] >= 1, (site, out["site_entries"])

@@ -24,6 +24,7 @@ additionally store arbitrary Python objects.
 """
 from __future__ import annotations
 
+import contextlib
 import threading
 from typing import Any, Optional, Tuple
 
@@ -34,10 +35,21 @@ from repro.core import modes as M
 from repro.core.engine import AbortTx
 from repro.core.stats_schema import RECOVERY_STAT_KEYS, base_stats
 from repro.reliability import faultpoints as FP
+from repro.runtime.tracing import span
 
-__all__ = ["MVStoreHandle"]
+__all__ = ["ABORT_REASONS", "MVStoreHandle"]
 
 _COUNTER_KEYS = ("commits", "aborts", "ro_commits", "versioned_commits")
+
+#: why a transaction aborted, as ``stats()["abort_reasons"]`` counts it:
+#:   clock            an unversioned read found the clock past its begin
+#:   ring_window      a versioned read found no ring slot at/below its clock
+#:   donation_race    a commit donated the row buffer the read was using
+#:   commit_conflict  commit-time validation found a newer commit
+#:   versioned_write  a versioned transaction wrote (restarts unversioned)
+#:   explicit         ``abort`` of a live transaction (its body raised)
+ABORT_REASONS = ("clock", "ring_window", "donation_race", "commit_conflict",
+                 "versioned_write", "explicit")
 
 _RACED = object()   # a device read lost the race against a donating commit
 
@@ -120,6 +132,8 @@ class MVStoreHandle(SubstrateBase):
         self._readers = [self.controller.reader() for _ in range(n_threads)]
         self._counters = [{k: 0 for k in _COUNTER_KEYS}
                          for _ in range(n_threads)]
+        self._abort_reasons = [dict.fromkeys(ABORT_REASONS, 0)
+                               for _ in range(n_threads)]
         self._no_version = [False] * n_threads
         self._state = None
         self._snap: Tuple = (0, np.zeros((0,), np.int32), None, None)
@@ -167,7 +181,7 @@ class MVStoreHandle(SubstrateBase):
             if not _donation_raced(e):
                 raise
             if ctx is not None:
-                self._abort_ctx(ctx)
+                self._abort_ctx(ctx, "donation_race")
             return _RACED
 
     # -- Substrate protocol ----------------------------------------------
@@ -193,21 +207,26 @@ class MVStoreHandle(SubstrateBase):
         ctx.read_cnt += 1
         if addr in ctx.write_buf:
             return ctx.write_buf[addr]
-        clock, live, ring, ring_ts = self._snap
-        if ctx.versioned and ctx.read_only:
-            if ring is None:
-                # Mode-Q reader versions the block itself (paper SS4.1's
-                # reader-triggered versioning, at block granularity)
-                clock, live, ring, ring_ts = self._version_block()
-            slot = _ring_slot(ring_ts, ctx.read_clock)
-            if slot is None:
-                self._abort_ctx(ctx)       # fell out of the ring window
-            return self._read_device(lambda: ring[slot, addr].item(), ctx)
-        # unversioned (Mode-Q reader / writer encounter read): validate
-        # that no commit has advanced the clock past our begin snapshot
-        if clock > ctx.read_clock:
-            self._abort_ctx(ctx)
-        return self._read_device(lambda: live[addr].item(), ctx)
+        versioned = ctx.versioned and ctx.read_only
+        with span("mvstore.read", path="ring" if versioned else "live"):
+            clock, live, ring, ring_ts = self._snap
+            if versioned:
+                if ring is None:
+                    # Mode-Q reader versions the block itself (paper
+                    # SS4.1's reader-triggered versioning, at block
+                    # granularity)
+                    clock, live, ring, ring_ts = self._version_block()
+                slot = _ring_slot(ring_ts, ctx.read_clock)
+                if slot is None:
+                    self._abort_ctx(ctx, "ring_window")
+                return self._read_device(lambda: ring[slot, addr].item(),
+                                         ctx)
+            # unversioned (Mode-Q reader / writer encounter read):
+            # validate that no commit has advanced the clock past our
+            # begin snapshot
+            if clock > ctx.read_clock:
+                self._abort_ctx(ctx, "clock")
+            return self._read_device(lambda: live[addr].item(), ctx)
 
     def read_bulk(self, ctx: _MVCtx, addrs) -> Any:
         """`Txn.read_bulk` at the store level: one slice per batch.
@@ -228,20 +247,23 @@ class MVStoreHandle(SubstrateBase):
         from repro.core.engine.bulkread import as_addr_array
         a = as_addr_array(addrs)
         ctx.read_cnt += a.size
-        clock, live, ring, ring_ts = self._snap
-        if ctx.versioned and ctx.read_only:
-            if ring is None:
-                clock, live, ring, ring_ts = self._version_block()
-            slot = _ring_slot(ring_ts, ctx.read_clock)
-            if slot is None:
-                self._abort_ctx(ctx)       # fell out of the ring window
-            vals = self._read_device(
-                lambda: self._gather_row(ring[slot], a), ctx)
-        else:
-            if clock > ctx.read_clock:
-                self._abort_ctx(ctx)
-            vals = self._read_device(
-                lambda: self._gather_row(live, a), ctx)
+        versioned = ctx.versioned and ctx.read_only
+        with span("mvstore.read_bulk", words=int(a.size),
+                  path="ring" if versioned else "live"):
+            clock, live, ring, ring_ts = self._snap
+            if versioned:
+                if ring is None:
+                    clock, live, ring, ring_ts = self._version_block()
+                slot = _ring_slot(ring_ts, ctx.read_clock)
+                if slot is None:
+                    self._abort_ctx(ctx, "ring_window")
+                vals = self._read_device(
+                    lambda: self._gather_row(ring[slot], a), ctx)
+            else:
+                if clock > ctx.read_clock:
+                    self._abort_ctx(ctx, "clock")
+                vals = self._read_device(
+                    lambda: self._gather_row(live, a), ctx)
         if ctx.write_buf:
             return [ctx.write_buf.get(int(x), v)
                     for x, v in zip(a, vals.tolist())]
@@ -259,7 +281,7 @@ class MVStoreHandle(SubstrateBase):
             # the present: restart on the unversioned path, sticky for
             # this operation (mirrors Multiverse.tm_write)
             self._no_version[ctx.tid] = True
-            self._abort_ctx(ctx)
+            self._abort_ctx(ctx, "versioned_write")
         ctx.read_only = False
         ctx.write_buf[addr] = value
 
@@ -270,7 +292,7 @@ class MVStoreHandle(SubstrateBase):
         scatter (``engine/commit.scatter_row``)."""
         if ctx.versioned:
             self._no_version[ctx.tid] = True
-            self._abort_ctx(ctx)
+            self._abort_ctx(ctx, "versioned_write")
         ctx.read_only = False
         ctx.write_buf.update(zip((int(a) for a in addrs), values))
 
@@ -286,7 +308,7 @@ class MVStoreHandle(SubstrateBase):
         is the earliest safe one — firstObsModeUTs when valid, else the
         current clock (paper SS4.2); a reader whose snapshot is older than
         the seed then aborts via the no-valid-slot check."""
-        with self._commit_lock:
+        with span("mvstore.version_block"), self._locked():
             state = self._state
             if self._path not in state.ring:
                 state = self._mvstore.version_blocks(
@@ -294,6 +316,17 @@ class MVStoreHandle(SubstrateBase):
                     first_obs_mode_u_ts=self.controller.first_obs_mode_u_ts)
                 self._install(state)
         return self._snap
+
+    @contextlib.contextmanager
+    def _locked(self):
+        """Hold ``self._commit_lock``; the wait for it is the span
+        ``mvstore.commit.lock_wait``."""
+        with span("mvstore.commit.lock_wait"):
+            self._commit_lock.acquire()
+        try:
+            yield
+        finally:
+            self._commit_lock.release()
 
     def commit(self, txn: Txn) -> None:
         ctx = txn._ctx
@@ -307,13 +340,13 @@ class MVStoreHandle(SubstrateBase):
             ctx.active = False
             return
         conflict = False
-        with self._commit_lock:
+        with self._locked():
             if self._check_conflict(ctx):
                 conflict = True            # another step committed first
             else:
                 self._publish_locked(ctx)
         if conflict:
-            self._abort_ctx(ctx)
+            self._abort_ctx(ctx, "commit_conflict")
         c["commits"] += 1
         h.attempts = 0
         ctx.active = False
@@ -336,48 +369,51 @@ class MVStoreHandle(SubstrateBase):
         crashed member's parked context through exactly this path with
         ``wal_log=False`` (replay must not re-journal itself; the
         cross-shard caller journals the EPOCH instead)."""
-        if FP.ACTIVE is not None:
-            FP.fire("pre_clock_tick", ctx.tid)
-        state = self.controller.trainer_tick(self._state)
-        mode = self.controller.current_local_mode()
-        idx = np.array(sorted(ctx.write_buf), dtype=np.int64)
-        vals = np.array([ctx.write_buf[int(i)] for i in idx])
-        lsn = None
-        if wal_log and self.wal is not None and idx.size:
-            # PREPARE + DECIDE before the donating fused call: past the
-            # donation the old buffers are GONE, so the WAL record is
-            # the only thing a whole-process crash can recover from
-            lsn = self.wal.append_prepare(
-                ctx.tid, idx, vals,
-                clocks=(int(self._state.clock) + 1,),
-                shard=self.wal_shard)
-            self.wal.append_decide(lsn)
-        # ONE fused publish under the held commit lock (the
-        # seqlock bracket): scatter into the live row AND the
-        # PackedVLT ring refresh ride a single device-resident
-        # ``ops.commit_fused`` call — no scatter-then-rotate
-        # host round trip (``mvstore.mv_commit_fused``).  The
-        # fused call fires pre_scatter itself (before donation);
-        # from the call's return until _install the new state is
-        # parked in _inflight so recovery can finish the publish
-        state = self._mvstore.mv_commit_fused(
-            state, self._key, idx, vals, local_mode=mode,
-            cfg=self.cfg)
-        self._inflight = state
-        if FP.ACTIVE is not None:
-            FP.fire("post_scatter", ctx.tid)
-            FP.fire("pre_release", ctx.tid)
-        self._install(state)
-        self._inflight = None
-        if lsn is not None:
-            self.wal.append_complete(lsn)
+        with span("mvstore.publish", words=len(ctx.write_buf)):
+            if FP.ACTIVE is not None:
+                FP.fire("pre_clock_tick", ctx.tid)
+            state = self.controller.trainer_tick(self._state)
+            mode = self.controller.current_local_mode()
+            idx = np.array(sorted(ctx.write_buf), dtype=np.int64)
+            vals = np.array([ctx.write_buf[int(i)] for i in idx])
+            lsn = None
+            if wal_log and self.wal is not None and idx.size:
+                # PREPARE + DECIDE before the donating fused call: past the
+                # donation the old buffers are GONE, so the WAL record is
+                # the only thing a whole-process crash can recover from
+                lsn = self.wal.append_prepare(
+                    ctx.tid, idx, vals,
+                    clocks=(int(self._state.clock) + 1,),
+                    shard=self.wal_shard)
+                self.wal.append_decide(lsn)
+            # ONE fused publish under the held commit lock (the
+            # seqlock bracket): scatter into the live row AND the
+            # PackedVLT ring refresh ride a single device-resident
+            # ``ops.commit_fused`` call — no scatter-then-rotate
+            # host round trip (``mvstore.mv_commit_fused``).  The
+            # fused call fires pre_scatter itself (before donation);
+            # from the call's return until _install the new state is
+            # parked in _inflight so recovery can finish the publish
+            state = self._mvstore.mv_commit_fused(
+                state, self._key, idx, vals, local_mode=mode,
+                cfg=self.cfg)
+            self._inflight = state
+            if FP.ACTIVE is not None:
+                FP.fire("post_scatter", ctx.tid)
+                FP.fire("pre_release", ctx.tid)
+            # the ring_ts host copy in _install waits for the fused call
+            with span("mvstore.publish.install"):
+                self._install(state)
+            self._inflight = None
+            if lsn is not None:
+                self.wal.append_complete(lsn)
 
     def abort(self, txn: Txn) -> None:
         ctx = txn._ctx
         if not getattr(ctx, "active", False):
             return
         try:
-            self._abort_ctx(ctx)
+            self._abort_ctx(ctx, "explicit")
         except AbortTx:
             pass
 
@@ -397,8 +433,11 @@ class MVStoreHandle(SubstrateBase):
             return _ring_slot(ring_ts, ctx.read_clock) is not None
         return clock <= ctx.read_clock
 
-    def _abort_ctx(self, ctx: _MVCtx) -> None:
+    def _abort_ctx(self, ctx: _MVCtx, reason: str) -> None:
+        """Count the abort under ``reason`` (one of ``ABORT_REASONS``),
+        feed the heuristics, and raise ``AbortTx(reason)``."""
         self._counters[ctx.tid]["aborts"] += 1
+        self._abort_reasons[ctx.tid][reason] += 1
         h = self._readers[ctx.tid]
         if ctx.read_only:
             # read-only aborts drive the paper's heuristics (K1 go-
@@ -407,13 +446,13 @@ class MVStoreHandle(SubstrateBase):
         else:
             h.attempts += 1
         ctx.active = False
-        raise AbortTx()
+        raise AbortTx(reason)
 
     # -- heap -------------------------------------------------------------
     def alloc(self, n: int, init: Any = None) -> int:
         jnp = self._jnp
         fill = 0 if init is None else init
-        with self._commit_lock:
+        with self._locked():
             state = self._state
             live = state.live[self._key]
             base = int(live.shape[0])
@@ -485,6 +524,8 @@ class MVStoreHandle(SubstrateBase):
         for c in self._counters:
             for k in _COUNTER_KEYS:
                 out[k] += c[k]
+        out["abort_reasons"] = {
+            r: sum(c[r] for c in self._abort_reasons) for r in ABORT_REASONS}
         out["mode_cas"] = sum(h.stats["mode_cas"] for h in self._readers)
         out["mode_transitions"] = self.controller.stats["mode_transitions"]
         out["unversioned_buckets"] = self.controller.stats[

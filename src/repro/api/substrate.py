@@ -31,6 +31,7 @@ import time
 from typing import Any, Callable, Optional, Protocol, runtime_checkable
 
 from repro.core.engine import AbortTx, MaxRetriesExceeded
+from repro.runtime.tracing import next_op, span
 
 __all__ = [
     "AbortTx", "MaxRetriesExceeded", "Substrate", "SubstrateBase", "Txn",
@@ -311,39 +312,52 @@ def run(tm: Any, fn: Callable[[Txn], Any], tid: int = 0,
     `backoff_s` > 0 sleeps an exponentially growing, jittered interval
     between attempts (capped at 10ms) — off by default because the GIL
     already serializes this port's contention.
+
+    Each attempt is the span ``tm.attempt`` (``repro.runtime.tracing``)
+    with ``tid``, ``op`` (shared by the attempts of one call) and
+    ``attempt``, and at its end ``outcome``: ``commit``,
+    ``abort.<reason>`` (``AbortTx.reason``, ``unclassified`` where the
+    backend gives none) or ``error``.
     """
     sub = as_substrate(tm)
     op_reset = getattr(sub, "begin_operation", None)
     if op_reset is not None:        # optional hook; bare Substrate
         op_reset(tid)               # implementations may omit it
+    op = next_op()
     tries = 0
     while True:
-        txn = sub.begin(tid)
-        try:
-            result = fn(txn)
-            sub.commit(txn)
-            return result
-        except AbortTx:
-            sub.abort(txn)               # no-op if the backend rolled back
-            tries += 1
-            if max_retries and tries >= max_retries:
-                cleanup = getattr(sub, "on_retries_exhausted", None)
-                if cleanup is not None:
-                    cleanup(tid)         # release locks, flush retires
-                raise MaxRetriesExceeded(
-                    f"{sub.name}: txn exceeded {max_retries} retries")
-            if backoff_s:
-                delay = min(_BACKOFF_CAP_S, backoff_s * (1 << min(tries, 10)))
-                time.sleep(delay * random.random())
-        except BaseException as e:
-            # user-code exception mid-attempt: roll back so the TM is not
-            # poisoned (locks held / writes unrolled), then propagate —
-            # unless it's a simulated crash (reliability/faultpoints),
-            # whose whole point is that no cleanup frame ever runs and
-            # recovery must reconstruct consistency from the wreckage
-            if not getattr(e, "simulated_crash", False):
-                sub.abort(txn)
-            raise
+        with span("tm.attempt", tid=tid, op=op, attempt=tries + 1) as sp:
+            txn = sub.begin(tid)
+            try:
+                result = fn(txn)
+                sub.commit(txn)
+            except AbortTx as e:
+                sub.abort(txn)           # no-op if the backend rolled back
+                sp.set(outcome="abort." + (e.reason or "unclassified"))
+            except BaseException as e:
+                # user-code exception mid-attempt: roll back so the TM is
+                # not poisoned (locks held / writes unrolled), then
+                # propagate — unless it's a simulated crash
+                # (reliability/faultpoints), whose whole point is that no
+                # cleanup frame ever runs and recovery must reconstruct
+                # consistency from the wreckage
+                if not getattr(e, "simulated_crash", False):
+                    sub.abort(txn)
+                sp.set(outcome="error")
+                raise
+            else:
+                sp.set(outcome="commit")
+                return result
+        tries += 1
+        if max_retries and tries >= max_retries:
+            cleanup = getattr(sub, "on_retries_exhausted", None)
+            if cleanup is not None:
+                cleanup(tid)             # release locks, flush retires
+            raise MaxRetriesExceeded(
+                f"{sub.name}: txn exceeded {max_retries} retries")
+        if backoff_s:
+            delay = min(_BACKOFF_CAP_S, backoff_s * (1 << min(tries, 10)))
+            time.sleep(delay * random.random())
 
 
 def atomic(tm: Any, tid: int = 0, max_retries: int = 0,

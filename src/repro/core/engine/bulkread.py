@@ -52,6 +52,8 @@ from typing import Any, Sequence
 
 import numpy as np
 
+from repro.runtime.tracing import span
+
 __all__ = ["as_addr_array", "bulk_read_lockver", "finish_with_scalar",
            "gather_row", "heap_gather", "shard_partition"]
 
@@ -96,7 +98,9 @@ def gather_row(row, addrs: np.ndarray) -> np.ndarray:
     if ops.on_tpu():
         from repro.core.engine.arrayheap import check_addr_bounds
         check_addr_bounds(addrs, row.shape[0])
-        return np.asarray(ops.snapshot_read(row, addrs))
+        out = np.asarray(ops.snapshot_read(row, addrs))
+        ops.COUNTS.moved("gather_read", d2h=out.nbytes)
+        return out
     if isinstance(row, np.ndarray):
         return row[addrs]
     if hasattr(row, "shape"):
@@ -128,7 +132,11 @@ def heap_gather(heap, addrs: np.ndarray):
         from repro.kernels import ops
         if ops.on_tpu():
             if heap.fits_int32:
-                return gather_row(heap.jnp(), addrs)
+                with span("tm.read_bulk.heap_upload") as sp:
+                    row = heap.jnp()
+                    ops.COUNTS.moved("heap_upload", h2d=row.nbytes)
+                    sp.set(h2d_bytes=row.nbytes)
+                return gather_row(row, addrs)
             ops.COUNTS.twin("gather_read")
     return g(addrs)
 
@@ -151,10 +159,13 @@ def bulk_read_lockver(eng, d, addrs: np.ndarray, *, inclusive: bool,
     accepted as-is, unvalidated and untracked, like the scalar paths.
     """
     locks = eng.locks
-    idxs = locks.index_bulk(addrs)
-    ver1, _, meta1 = locks.gather(idxs)
-    vals = heap_gather(eng.heap, addrs)
-    ver2, _, meta2 = locks.gather(idxs)
+    with span("tm.read_bulk.lock_gather"):
+        idxs = locks.index_bulk(addrs)
+        ver1, _, meta1 = locks.gather(idxs)
+    with span("tm.read_bulk.heap_gather"):
+        vals = heap_gather(eng.heap, addrs)
+    with span("tm.read_bulk.lock_gather"):
+        ver2, _, meta2 = locks.gather(idxs)
     # locked-by-me also fails here: the scalar fallback resolves own locks
     # exactly (and encounter-time policies reach own writes via d.undo)
     stable = ver1 == ver2
@@ -201,6 +212,8 @@ def finish_with_scalar(eng, d, addrs: np.ndarray, vals, ok, scalar_read):
     if bool(ok.all()):
         return vals
     out = vals if isinstance(vals, list) else vals.tolist()
-    for i in np.nonzero(~ok)[0]:
-        out[i] = scalar_read(eng, d, int(addrs[i]))
+    redo = np.nonzero(~ok)[0]
+    with span("tm.read_bulk.scalar_walk", words=int(redo.size)):
+        for i in redo:
+            out[i] = scalar_read(eng, d, int(addrs[i]))
     return out

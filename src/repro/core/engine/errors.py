@@ -8,7 +8,14 @@ from __future__ import annotations
 
 
 class AbortTx(Exception):
-    """Transaction abort (longjmp back to beginTxn)."""
+    """Transaction abort (longjmp back to beginTxn).
+
+    ``reason`` names the cause where the backend classifies it
+    (``repro.api.mvhandle.ABORT_REASONS``), else None."""
+
+    def __init__(self, reason=None):
+        super().__init__(*(() if reason is None else (reason,)))
+        self.reason = reason
 
 
 class MaxRetriesExceeded(Exception):

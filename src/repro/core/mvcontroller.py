@@ -33,6 +33,7 @@ from repro.configs.paper_stm import MultiverseParams
 from repro.core import heuristics as heur
 from repro.core import modes as M
 from repro.core import mvstore
+from repro.runtime.tracing import span
 
 
 class ReaderHandle:
@@ -46,8 +47,7 @@ class ReaderHandle:
         self.versioned = False
         self.local_mode_counter = 0
         self.initial_versioned_ts: Optional[int] = None
-        self.stats = {"commits": 0, "aborts": 0, "versioned_commits": 0,
-                      "mode_cas": 0}
+        self.stats = {"mode_cas": 0}
 
     # -- reader lifecycle -----------------------------------------------
     def begin(self, read_clock: int) -> Dict:
@@ -63,7 +63,6 @@ class ReaderHandle:
     def on_abort(self, read_cnt: int, wanted_blocks=()) -> None:
         """A snapshot read came back not-ok (writer advanced the clock or
         ring overflow) — the paper's reader abort path."""
-        self.stats["aborts"] += 1
         p = self.ctl.params
         if heur.should_attempt_mode_cas(
                 p, versioned=self.versioned, attempts=self.attempts,
@@ -81,9 +80,7 @@ class ReaderHandle:
         self.attempts += 1
 
     def on_commit(self, read_cnt: int, commit_clock: int) -> None:
-        self.stats["commits"] += 1
         if self.versioned:
-            self.stats["versioned_commits"] += 1
             self.ann.commit_ts_delta = commit_clock - (
                 self.initial_versioned_ts or 0)
             if M.get_mode(self.local_mode_counter) == M.MODE_U:
@@ -222,7 +219,8 @@ class MVController:
 
     def _bg_loop(self) -> None:
         while not self._stop.is_set():
-            self.step_once()
+            with span("mvcontroller.step"):
+                self.step_once()
             time.sleep(self._poll)
 
     def _unversion_round(self) -> None:

@@ -56,7 +56,7 @@ from typing import Any, List, Optional, Tuple
 
 import numpy as np
 
-from repro.api.mvhandle import MVStoreHandle, _MVCtx
+from repro.api.mvhandle import ABORT_REASONS, MVStoreHandle, _MVCtx
 from repro.api.substrate import SubstrateBase, Txn
 from repro.core import modes as M
 from repro.core.clock import AtomicInt
@@ -145,6 +145,8 @@ class ShardStoreHandle(SubstrateBase):
         self._top = 0
         self._counters = [{k: 0 for k in _COUNTER_KEYS}
                           for _ in range(n_threads)]
+        self._abort_reasons = [dict.fromkeys(ABORT_REASONS, 0)
+                               for _ in range(n_threads)]
         self._cross_commits = 0
         # durable commit log (reliability/wal.attach_wal sets this AND
         # each member shard's ``wal``/``wal_shard``): single-shard
@@ -199,8 +201,8 @@ class ShardStoreHandle(SubstrateBase):
         s, local = self._route1(addr)
         try:
             return self._shards[s].read(ctx.subs[s], local)
-        except AbortTx:
-            self._fail(ctx)
+        except AbortTx as e:
+            self._fail(ctx, e.reason)
             raise
 
     def read_bulk(self, ctx: _ShardCtx, addrs) -> Any:
@@ -220,16 +222,16 @@ class ShardStoreHandle(SubstrateBase):
                 for p, v in zip(pos.tolist(), vlist):
                     out[p] = v
             return out
-        except AbortTx:
-            self._fail(ctx)
+        except AbortTx as e:
+            self._fail(ctx, e.reason)
             raise
 
     def write(self, ctx: _ShardCtx, addr: int, value: Any) -> None:
         s, local = self._route1(addr)
         try:
             self._shards[s].write(ctx.subs[s], local, value)
-        except AbortTx:
-            self._fail(ctx)
+        except AbortTx as e:
+            self._fail(ctx, e.reason)
             raise
 
     def write_bulk(self, ctx: _ShardCtx, addrs, values) -> None:
@@ -248,8 +250,8 @@ class ShardStoreHandle(SubstrateBase):
                 self._shards[s].write_bulk(
                     ctx.subs[s], local[pos],
                     [vlist[p] for p in pos.tolist()])
-        except AbortTx:
-            self._fail(ctx)
+        except AbortTx as e:
+            self._fail(ctx, e.reason)
             raise
 
     def txn_alloc(self, ctx: _ShardCtx, n: int, init: Any = None) -> int:
@@ -292,8 +294,8 @@ class ShardStoreHandle(SubstrateBase):
             try:
                 self._shards[s].commit(Txn(self._shards[s], subs[s],
                                            ctx.tid))
-            except AbortTx:
-                self._fail(ctx)
+            except AbortTx as e:
+                self._fail(ctx, e.reason)
                 raise
         else:
             self._commit_cross(ctx, touched, write_shards)
@@ -375,21 +377,22 @@ class ShardStoreHandle(SubstrateBase):
             c.active = False
         ctx.active = False
 
-    def _fail(self, ctx: _ShardCtx) -> None:
+    def _fail(self, ctx: _ShardCtx, reason: str) -> None:
         """A shard-level abort surfaced: the shard already did its own
-        accounting/heuristics; record ONE logical abort and retire every
-        sub-context."""
+        accounting/heuristics; record ONE logical abort under ``reason``
+        and retire every sub-context."""
         self._counters[ctx.tid]["aborts"] += 1
+        self._abort_reasons[ctx.tid][reason] += 1
         self._deactivate(ctx)
 
     def _abort_cross(self, ctx: _ShardCtx, touched: List[int]) -> None:
         for s in touched:
             try:
-                self._shards[s]._abort_ctx(ctx.subs[s])
+                self._shards[s]._abort_ctx(ctx.subs[s], "commit_conflict")
             except AbortTx:
                 pass
-        self._fail(ctx)
-        raise AbortTx()
+        self._fail(ctx, "commit_conflict")
+        raise AbortTx("commit_conflict")
 
     def abort(self, txn: Txn) -> None:
         ctx = txn._ctx
@@ -398,10 +401,10 @@ class ShardStoreHandle(SubstrateBase):
         for s in self._touched(ctx):
             if ctx.subs[s].active:
                 try:
-                    self._shards[s]._abort_ctx(ctx.subs[s])
+                    self._shards[s]._abort_ctx(ctx.subs[s], "explicit")
                 except AbortTx:
                     pass
-        self._fail(ctx)
+        self._fail(ctx, "explicit")
 
     # -- heap --------------------------------------------------------------
     def alloc(self, n: int, init: Any = None) -> int:
@@ -477,6 +480,8 @@ class ShardStoreHandle(SubstrateBase):
         for c in self._counters:
             for k in _COUNTER_KEYS:
                 out[k] += c[k]
+        out["abort_reasons"] = {
+            r: sum(c[r] for c in self._abort_reasons) for r in ABORT_REASONS}
         out["mode_cas"] = sum(h.stats["mode_cas"]
                               for sh in self._shards
                               for h in sh._readers)

@@ -21,6 +21,9 @@ Keys:
   locks_swept          orphaned lock words the owner scan released
   torn_rows_repaired   torn PackedVLT mirror rows reset by recovery
   wal_records_replayed durable WAL records replayed on restart
+  abort_reasons        ``{reason: count}`` of the aborts, summing to
+                       ``aborts``, on backends that classify them
+                       (the versioned stores); ``{}`` elsewhere
   mode                 current global mode name ("Q"/"QtoU"/"U"/"UtoQ"),
                        or "-" for backends with no mode machinery
   backend              backend class/registry name
@@ -53,12 +56,13 @@ STATS_COUNTER_KEYS = (
     "ebr_freed",
 ) + RECOVERY_STAT_KEYS
 
-STATS_KEYS = STATS_COUNTER_KEYS + ("mode", "backend")
+STATS_KEYS = STATS_COUNTER_KEYS + ("abort_reasons", "mode", "backend")
 
 
 def base_stats(backend: str = "", mode: str = "-") -> Dict[str, object]:
     """A zeroed stats dict in the shared schema."""
     out: Dict[str, object] = {k: 0 for k in STATS_COUNTER_KEYS}
+    out["abort_reasons"] = {}
     out["mode"] = mode
     out["backend"] = backend
     return out
@@ -77,4 +81,5 @@ def normalize_stats(raw: Optional[Dict], backend: str = "",
     for k in STATS_COUNTER_KEYS:
         if k in raw:
             out[k] = int(raw[k])
+    out["abort_reasons"] = dict(raw.get("abort_reasons") or {})
     return out

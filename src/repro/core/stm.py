@@ -51,6 +51,7 @@ from repro.core.engine import (
 from repro.core.engine.engine import _Tx  # noqa: F401 (historical export)
 from repro.core.vlt import DELETED_TS, VLT, VersionList, VListNode
 from repro.reliability import faultpoints as FP
+from repro.runtime.tracing import span
 
 __all__ = ["AbortTx", "MaxRetriesExceeded", "Multiverse",
            "MultiversePolicy", "TMBase", "run"]
@@ -82,8 +83,11 @@ class MultiversePolicy(PolicyBase):
                              for _ in range(eng.n_threads)]
         self.stats_unversioned_buckets = 0
         self.stats_mode_transitions = 0
-        self.stats_version_gather_hits = 0   # words resolved by the
-        #                                      packed-VLT bulk gather
+        # words each tier of read_bulk resolved: the lock-version
+        # batch, the packed-VLT mirror gather, the scalar walk
+        self.stats_bulk_batch_words = 0
+        self.stats_version_gather_hits = 0
+        self.stats_bulk_scalar_words = 0
         self._stop = threading.Event()
         self._bg: Optional[threading.Thread] = None
         if self._start_bg:
@@ -428,10 +432,15 @@ class MultiversePolicy(PolicyBase):
         """
         if not d.versioned:
             vals, ok = B.bulk_read_lockver(eng, d, addrs, inclusive=False)
+            batch = int(ok.sum())
+            self.stats_bulk_batch_words += batch
+            self.stats_bulk_scalar_words += ok.size - batch
             return B.finish_with_scalar(eng, d, addrs, vals, ok, self.read)
         vals, ok = B.bulk_read_lockver(eng, d, addrs, inclusive=False,
                                        track=False)
+        self.stats_bulk_batch_words += int(ok.sum())
         vals, ok = self._bulk_versioned_gather(eng, d, addrs, vals, ok)
+        self.stats_bulk_scalar_words += ok.size - int(ok.sum())
         scalar = (self._mode_u_versioned_read if d.local_mode == M.MODE_U
                   else self._mode_q_versioned_read)
         return B.finish_with_scalar(eng, d, addrs, vals, ok, scalar)
@@ -461,12 +470,13 @@ class MultiversePolicy(PolicyBase):
             return vals, ok
         bad = np.nonzero(~ok)[0]
         sub = addrs[bad]
-        idxs = eng.locks.index_bulk(sub)
-        # the lock gate: gathered BEFORE the mirror rows (GIL program
-        # order), unlocked AND unflagged required
-        _, _, meta = eng.locks.gather(idxs)
-        free = (meta & 3) == 0
-        mvals, mok = self.vlt.mirror.select(idxs, sub, d.r_clock)
+        with span("tm.read_bulk.mirror_select", words=int(bad.size)):
+            idxs = eng.locks.index_bulk(sub)
+            # the lock gate: gathered BEFORE the mirror rows (GIL program
+            # order), unlocked AND unflagged required
+            _, _, meta = eng.locks.gather(idxs)
+            free = (meta & 3) == 0
+            mvals, mok = self.vlt.mirror.select(idxs, sub, d.r_clock)
         mok &= free
         hit = bad[mok]
         if hit.size == 0:
@@ -680,10 +690,13 @@ class MultiversePolicy(PolicyBase):
         out["unversioned_buckets"] = self.stats_unversioned_buckets
         out["ebr_freed"] = self.ebr.freed_count
         # raw-engine stats only (the normalized substrate schema drops
-        # them): words a versioned bulk read resolved via PackedVLT.select,
-        # and how many of those a non-primary mirror way served (bucket
-        # collisions the multi-way row layout kept vectorizable)
+        # them): words read_bulk resolved in the lock-version batch, via
+        # PackedVLT.select, and by the scalar walk; and how many of the
+        # mirror's hits a non-primary way served (bucket collisions the
+        # multi-way row layout kept vectorizable)
+        out["bulk_batch_words"] = self.stats_bulk_batch_words
         out["version_gather_hits"] = self.stats_version_gather_hits
+        out["bulk_scalar_words"] = self.stats_bulk_scalar_words
         out["mirror_way2_hits"] = sum(self.vlt.mirror.way_hits[1:])
 
     def stop(self, eng) -> None:

@@ -10,7 +10,10 @@ kernels) take their twin or pure-jnp reference.  ``interpret=True``
 runs a kernel in the Pallas interpreter instead; only tests ask for it.
 
 ``COUNTS`` records, per kernel, how often a wrapper entered the kernel
-branch and how often a batch took the int64 numpy twin instead.
+branch, how often a batch took the int64 numpy twin instead, and the
+host bytes each launch uploaded and copied back.  Each launch is the
+span ``kernel.<name>`` (``repro.runtime.tracing``) with ``n``, the
+batch's elements, and ``h2d_bytes``.
 """
 from __future__ import annotations
 
@@ -33,6 +36,7 @@ from repro.kernels import snapshot_select as _ss
 from repro.kernels import ssd_scan as _ssd
 from repro.kernels import validate as _val
 from repro.kernels import version_select as _vs
+from repro.runtime.tracing import span
 
 #: lane width of every lane-dense kernel layout
 LANES = 128
@@ -55,14 +59,19 @@ def on_tpu() -> bool:
 
 
 class KernelCounts:
-    """Per-kernel counts of kernel-branch entries and int64 twin routes
+    """Per-kernel counts of kernel-branch entries, int64 twin routes
     (batches holding words beyond int32, which the x64-less device path
-    would truncate).  Thread-safe; ``reset`` starts a new window."""
+    would truncate), host bytes uploaded for launches (``h2d_bytes``)
+    and device bytes copied back to the host (``d2h_bytes``); the key
+    ``heap_upload`` counts ``ArrayHeap.jnp()`` copies of the word heap.
+    Thread-safe; ``reset`` starts a new window."""
 
     def __init__(self):
         self._lock = threading.Lock()
         self.entries = collections.Counter()
         self.twin_routes = collections.Counter()
+        self.h2d_bytes = collections.Counter()
+        self.d2h_bytes = collections.Counter()
 
     def enter(self, kernel: str) -> None:
         with self._lock:
@@ -72,10 +81,17 @@ class KernelCounts:
         with self._lock:
             self.twin_routes[kernel] += 1
 
+    def moved(self, kernel: str, h2d: int = 0, d2h: int = 0) -> None:
+        """Count ``h2d`` bytes uploaded and ``d2h`` copied back."""
+        with self._lock:
+            self.h2d_bytes[kernel] += h2d
+            self.d2h_bytes[kernel] += d2h
+
     def reset(self) -> None:
         with self._lock:
-            self.entries.clear()
-            self.twin_routes.clear()
+            for c in (self.entries, self.twin_routes, self.h2d_bytes,
+                      self.d2h_bytes):
+                c.clear()
 
 
 COUNTS = KernelCounts()
@@ -166,8 +182,10 @@ def snapshot_select(ring, ts, read_clock, *, interpret: bool = False):
     flat = ring.reshape(R, -1)
     if interpret or on_tpu():
         COUNTS.enter("snapshot_select")
-        val, ok = _ss.snapshot_select_flat(flat, ts, read_clock,
-                                           interpret=interpret)
+        with span("kernel.snapshot_select", n=int(flat.shape[1]),
+                  h2d_bytes=0):
+            val, ok = _ss.snapshot_select_flat(flat, ts, read_clock,
+                                               interpret=interpret)
     else:
         val, ok = _ref.snapshot_select_ref(flat, ts, read_clock)
     return val.reshape(shape), ok
@@ -201,9 +219,14 @@ def snapshot_read(heap, addrs, tile: int = 1024, *,
     if n == 0:
         return jnp.zeros((0,), hj.dtype)
     COUNTS.enter("gather_read")
-    t = tile_for(n, tile)
-    a = _padded(np.asarray(addrs, np.int32), -(-n // t) * t, _gr.PAD_ADDR)
-    return _gather(hj, jnp.asarray(a), tile=t, interpret=interpret)[:n]
+    with span("kernel.gather_read", n=n) as sp:
+        t = tile_for(n, tile)
+        a = _padded(np.asarray(addrs, np.int32), -(-n // t) * t,
+                    _gr.PAD_ADDR)
+        h2d = a.nbytes + (0 if isinstance(heap, jax.Array) else hj.nbytes)
+        COUNTS.moved("gather_read", h2d=h2d)
+        sp.set(h2d_bytes=h2d)
+        return _gather(hj, jnp.asarray(a), tile=t, interpret=interpret)[:n]
 
 
 @functools.partial(jax.jit, donate_argnums=(0,),
@@ -217,11 +240,15 @@ def _scatter(row, addrs, values, *, tile, interpret):
 
 def _scatter_launch(row, addrs_np, values, tile, interpret):
     """Sort, pad and launch one scatter kernel over a jax row."""
-    _, a, v, t = _sorted_batch(addrs_np, np.asarray(values),
-                               int(row.shape[0]), tile)
     COUNTS.enter("scatter_write")
-    return _scatter(row, jnp.asarray(a, jnp.int32),
-                    jnp.asarray(v, row.dtype), tile=t, interpret=interpret)
+    with span("kernel.scatter_write", n=int(addrs_np.shape[0])) as sp:
+        _, a, v, t = _sorted_batch(addrs_np, np.asarray(values),
+                                   int(row.shape[0]), tile)
+        a32, vr = np.asarray(a, np.int32), np.asarray(v, row.dtype)
+        COUNTS.moved("scatter_write", h2d=a32.nbytes + vr.nbytes)
+        sp.set(h2d_bytes=a32.nbytes + vr.nbytes)
+        return _scatter(row, jnp.asarray(a32), jnp.asarray(vr), tile=t,
+                        interpret=interpret)
 
 
 def write_back(heap, addrs, values, tile: int = 1024, *,
@@ -255,7 +282,11 @@ def write_back(heap, addrs, values, tile: int = 1024, *,
         COUNTS.twin("scatter_write")
         return _sw.np_write_back(np.asarray(heap), addrs_np, vals)
     hj = jnp.array(heap)                   # a copy: the launch donates it
-    return np.asarray(_scatter_launch(hj, addrs_np, vals, tile, interpret))
+    if heap_np is not None:
+        COUNTS.moved("scatter_write", h2d=hj.nbytes)
+    out = np.asarray(_scatter_launch(hj, addrs_np, vals, tile, interpret))
+    COUNTS.moved("scatter_write", d2h=out.nbytes)
+    return out
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -390,40 +421,50 @@ def commit_fused(heap, w_addr, w_val, w_seg,
         out = (new_heap, ok, stamp(ok))
     else:
         COUNTS.enter("commit_fused")
-        hj = jnp.asarray(heap)
-        order, a32, v, t = _sorted_batch(w_addr, vals, int(hj.shape[0]),
-                                         tile)
-        s32 = _padded(w_seg[order], a32.shape[0], 0)
+        with span("kernel.commit_fused", n=int(w_addr.shape[0])) as sp:
+            hj = jnp.asarray(heap)
+            h2d = 0 if heap_np is None else hj.nbytes
+            order, a32, v, t = _sorted_batch(w_addr, vals,
+                                             int(hj.shape[0]), tile)
+            s32 = _padded(w_seg[order], a32.shape[0], 0)
 
-        def rel(x):
-            return np.clip(np.asarray(x, np.int64) - base, _LO32, _HI32)
+            def rel(x):
+                return np.clip(np.asarray(x, np.int64) - base, _LO32,
+                               _HI32)
 
-        # dummy txn slot T absorbs the pad rows of empty side batches
-        tids_p = np.concatenate([np.asarray(tids, np.int64), [0]])
-        rcs_p = np.concatenate([rel(r_clocks), [0]])
-        dummy = len(tids_p) - 1
+            # dummy txn slot T absorbs the pad rows of empty side batches
+            tids_p = np.concatenate([np.asarray(tids, np.int64), [0]])
+            rcs_p = np.concatenate([rel(r_clocks), [0]])
+            dummy = len(tids_p) - 1
 
-        def side(ver_rel, own, meta, seen_rel, seg):
-            if seg.size:
-                return ver_rel, own, meta, seen_rel, seg
-            z = np.zeros(1, np.int64)
-            return z, z.astype(np.int32), z.astype(np.int32), z, \
-                np.full(1, dummy, np.int64)
+            def side(ver_rel, own, meta, seen_rel, seg):
+                if seg.size:
+                    return ver_rel, own, meta, seen_rel, seg
+                z = np.zeros(1, np.int64)
+                return z, z.astype(np.int32), z.astype(np.int32), z, \
+                    np.full(1, dummy, np.int64)
 
-        lv, lo_, lm, _, ls = side(rel(l_ver), l_own, l_meta,
-                                  np.zeros_like(l_ver), l_seg)
-        rv, ro, rm, rn, rs = side(rel(r_ver), r_own, r_meta,
-                                  rel(r_seen), r_seg)
+            lv, lo_, lm, _, ls = side(rel(l_ver), l_own, l_meta,
+                                      np.zeros_like(l_ver), l_seg)
+            rv, ro, rm, rn, rs = side(rel(r_ver), r_own, r_meta,
+                                      rel(r_seen), r_seg)
 
-        def i32(x):
-            return jnp.asarray(np.asarray(x), jnp.int32)
+            def up(x, dtype=np.int32):
+                # cast on the host: the upload is then the bytes counted
+                # here, and no convert program runs on the device
+                nonlocal h2d
+                x = np.asarray(x).astype(dtype, copy=False)
+                h2d += x.nbytes
+                return jnp.asarray(x)
 
-        new_heap, ok32, _ = _commit_fused_jit(
-            hj, i32(a32), jnp.asarray(v, hj.dtype), i32(s32),
-            i32(lv), i32(lo_), i32(lm), i32(ls),
-            i32(rv), i32(ro), i32(rm), i32(rn), i32(rs),
-            i32(tids_p), i32(rcs_p), jnp.zeros((1,), jnp.int32),
-            mode=int(mode), tile=t, interpret=interpret)
+            new_heap, ok32, _ = _commit_fused_jit(
+                hj, up(a32), up(v, hj.dtype), up(s32),
+                up(lv), up(lo_), up(lm), up(ls),
+                up(rv), up(ro), up(rm), up(rn), up(rs),
+                up(tids_p), up(rcs_p), jnp.zeros((1,), jnp.int32),
+                mode=int(mode), tile=t, interpret=interpret)
+            COUNTS.moved("commit_fused", h2d=h2d, d2h=4 * n_txn)
+            sp.set(h2d_bytes=h2d)
         ok = np.asarray(ok32[:n_txn]) != 0
         out = (new_heap, ok, stamp(ok))
     if ring is None:
@@ -472,6 +513,9 @@ def validate_readset(ver, own, meta, seen, r_clock, tid, mode,
     t = tile_for(n, tile)
     length = -(-n // t) * t
     p = _val.PAD
+    # four int32 columns of ``length`` entries and the three params
+    h2d = 4 * 4 * length + 4 * 3
+    COUNTS.moved("validate", h2d=h2d, d2h=1)
 
     def prep(x, fill):
         return jnp.asarray(_padded(np.asarray(x).astype(np.int32), length,
@@ -480,11 +524,12 @@ def validate_readset(ver, own, meta, seen, r_clock, tid, mode,
     def rel(x):
         return np.clip(np.asarray(x, np.int64) - base, _LO32, _HI32)
 
-    return bool(_validate(
-        prep(rel(ver), p["ver"]), prep(own, p["own"]),
-        prep(meta, p["meta"]), prep(rel(seen), p["seen"]),
-        jnp.asarray([0, int(tid), int(mode)], jnp.int32),
-        tile=t, interpret=interpret))
+    with span("kernel.validate", n=n, h2d_bytes=h2d):
+        return bool(_validate(
+            prep(rel(ver), p["ver"]), prep(own, p["own"]),
+            prep(meta, p["meta"]), prep(rel(seen), p["seen"]),
+            jnp.asarray([0, int(tid), int(mode)], jnp.int32),
+            tile=t, interpret=interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
@@ -524,14 +569,20 @@ def version_select(ts, data, r_clock, tile: int = 1024, *,
         return np_version_select(np.asarray(ts, np.int64), data,
                                  int(r_clock))
     COUNTS.enter("version_select")
-    rel = np.clip(np.asarray(ts, np.int64) - int(r_clock), _LO32, _HI32)
-    t = tile_for(n, tile)
-    length = -(-n // t) * t
-    vals, ok = _version_select(
-        jnp.asarray(_padded(rel.astype(np.int32), length, _vs.PAD_TS)),
-        jnp.asarray(_padded(data, length, 0), jnp.int32),
-        tile=t, interpret=interpret)
-    return np.asarray(vals[:n]), np.asarray(ok[:n]) != 0
+    with span("kernel.version_select", n=n) as sp:
+        rel = np.clip(np.asarray(ts, np.int64) - int(r_clock), _LO32,
+                      _HI32)
+        t = tile_for(n, tile)
+        length = -(-n // t) * t
+        ts32 = _padded(rel.astype(np.int32), length, _vs.PAD_TS)
+        data32 = _padded(data, length, 0).astype(np.int32, copy=False)
+        h2d = ts32.nbytes + data32.nbytes
+        sp.set(h2d_bytes=h2d)
+        vals, ok = _version_select(jnp.asarray(ts32), jnp.asarray(data32),
+                                   tile=t, interpret=interpret)
+        vals, ok = np.asarray(vals[:n]), np.asarray(ok[:n])
+    COUNTS.moved("version_select", h2d=h2d, d2h=vals.nbytes + ok.nbytes)
+    return vals, ok != 0
 
 
 def fused_adamw(p, g, m, v, ring, slot, *, lr, scale, count, b1, b2, eps,

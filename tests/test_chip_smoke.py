@@ -132,6 +132,14 @@ def test_tm_phase_tiny(kernel_branch):
     for site in ("gather_read", "validate", "version_select",
                  "commit_fused"):
         assert out["site_entries"][site] >= 1, site
+        assert out["h2d_bytes"][site] > 0, site
+    # the word heap is uploaded for every kernel gather, and gathered
+    # words come back to the host
+    assert out["h2d_bytes"]["heap_upload"] > 0
+    assert out["d2h_bytes"]["gather_read"] > 0
+    scan = out["scan"]
+    assert scan["bulk_batch_words"] > 0
+    assert min(scan["version_gather_hits"], scan["bulk_scalar_words"]) >= 0
 
 
 def test_serve_phase_smoke_width():
