@@ -16,8 +16,9 @@ Phases, each printing one ``phase <name> {...}`` line of its numbers
            burst on ``tl2`` and Mode-U ``mvstore`` commits; every
            completed scan must see the conserved sum, every device site
            must be entered and no batch may take the int64 twin route;
-           prints the words each ``read_bulk`` tier resolved and the
-           host bytes uploaded and copied back per kernel;
+           prints the words each ``read_bulk`` tier resolved, the
+           host bytes uploaded and copied back per kernel, and the
+           gather kernel's grid steps by path (block or row);
   serve    the snapshot server at full ``qwen2.5-3b`` width (random
            weights from a seed) in Mode Q, with store commits landing
            between requests;
@@ -420,6 +421,7 @@ def tm_phase(*, heap_words: int = 1 << 22, region: int = 1 << 20,
     out["int64_twin_routes"] = dict(ops.COUNTS.twin_routes)
     out["h2d_bytes"] = dict(ops.COUNTS.h2d_bytes)
     out["d2h_bytes"] = dict(ops.COUNTS.d2h_bytes)
+    out["gather_tiles"] = dict(ops.COUNTS.tiles)
     for site in ("gather_read", "validate", "version_select",
                  "commit_fused"):
         assert ops.COUNTS.entries[site] >= 1, (site, out["site_entries"])

@@ -98,9 +98,7 @@ def gather_row(row, addrs: np.ndarray) -> np.ndarray:
     if ops.on_tpu():
         from repro.core.engine.arrayheap import check_addr_bounds
         check_addr_bounds(addrs, row.shape[0])
-        out = np.asarray(ops.snapshot_read(row, addrs))
-        ops.COUNTS.moved("gather_read", d2h=out.nbytes)
-        return out
+        return ops.snapshot_read(row, addrs)
     if isinstance(row, np.ndarray):
         return row[addrs]
     if hasattr(row, "shape"):

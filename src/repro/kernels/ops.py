@@ -63,8 +63,10 @@ class KernelCounts:
     (batches holding words beyond int32, which the x64-less device path
     would truncate), host bytes uploaded for launches (``h2d_bytes``)
     and device bytes copied back to the host (``d2h_bytes``); the key
-    ``heap_upload`` counts ``ArrayHeap.jnp()`` copies of the word heap.
-    Thread-safe; ``reset`` starts a new window."""
+    ``heap_upload`` counts ``ArrayHeap.jnp()`` copies of the word heap;
+    ``tiles`` counts the gather kernel's grid steps by path
+    (``gather_block_tiles``, ``gather_row_tiles``).  Thread-safe;
+    ``reset`` starts a new window."""
 
     def __init__(self):
         self._lock = threading.Lock()
@@ -72,6 +74,7 @@ class KernelCounts:
         self.twin_routes = collections.Counter()
         self.h2d_bytes = collections.Counter()
         self.d2h_bytes = collections.Counter()
+        self.tiles = collections.Counter()
 
     def enter(self, kernel: str) -> None:
         with self._lock:
@@ -87,10 +90,16 @@ class KernelCounts:
             self.h2d_bytes[kernel] += h2d
             self.d2h_bytes[kernel] += d2h
 
+    def tiled(self, block: int, row: int) -> None:
+        """Count the gather kernel's grid steps by the path each took."""
+        with self._lock:
+            self.tiles["gather_block_tiles"] += block
+            self.tiles["gather_row_tiles"] += row
+
     def reset(self) -> None:
         with self._lock:
             for c in (self.entries, self.twin_routes, self.h2d_bytes,
-                      self.d2h_bytes):
+                      self.d2h_bytes, self.tiles):
                 c.clear()
 
 
@@ -193,23 +202,27 @@ def snapshot_select(ring, ts, read_clock, *, interpret: bool = False):
 
 @functools.partial(jax.jit, static_argnames=("tile", "interpret"))
 def _gather(heap, addrs, *, tile, interpret):
-    return _gr.gather_read_flat(_rows(heap), addrs, tile=tile,
-                                interpret=interpret).reshape(-1)
+    vals = _gr.gather_read_flat(_rows(heap), addrs, tile=tile,
+                                interpret=interpret)
+    return vals.reshape(-1), jnp.sum(_gr.consecutive_tiles(addrs, tile))
 
 
 def snapshot_read(heap, addrs, tile: int = 1024, *,
-                  interpret: bool = False):
+                  interpret: bool = False) -> np.ndarray:
     """Batched snapshot read: ``heap[addrs]`` in one gather launch.
 
     ``heap``: [H] of a 32-bit dtype, or a host int64 heap of int32-range
     words (one holding a wider word raises OverflowError: the device copy
     would truncate it, and the engine routes such heaps to the numpy
     twin first); ``addrs``: [N] int — returns the [N] gathered values as
-    a jax array.  Adapts ragged batch lengths to the tiled kernel by
+    a host array.  Adapts ragged batch lengths to the tiled kernel by
     padding with address 0 (always allocated — the heaps burn it as
-    NULL) and slicing the result back to N.  This is the
-    `Txn.read_bulk` / `snapshot_bulk` hot path on
-    TPU; on CPU the engine uses the numpy twin (a single fancy-index in
+    NULL) and slicing the result back to N on the host.  The values come
+    back with the count of the kernel's block steps in one copy; the
+    steps go into ``COUNTS.tiles`` as ``gather_block_tiles`` (a run of
+    consecutive addresses, one block copy) and ``gather_row_tiles``.
+    This is the `Txn.read_bulk` / `snapshot_bulk` hot path on TPU; on
+    CPU the engine uses the numpy twin (a single fancy-index in
     ``engine.bulkread.heap_gather``) directly.
     """
     n = int(addrs.shape[0])
@@ -217,7 +230,7 @@ def snapshot_read(heap, addrs, tile: int = 1024, *,
         raise OverflowError("heap holds a word beyond int32")
     hj = jnp.asarray(heap)
     if n == 0:
-        return jnp.zeros((0,), hj.dtype)
+        return np.zeros((0,), hj.dtype)
     COUNTS.enter("gather_read")
     with span("kernel.gather_read", n=n) as sp:
         t = tile_for(n, tile)
@@ -226,7 +239,12 @@ def snapshot_read(heap, addrs, tile: int = 1024, *,
         h2d = a.nbytes + (0 if isinstance(heap, jax.Array) else hj.nbytes)
         COUNTS.moved("gather_read", h2d=h2d)
         sp.set(h2d_bytes=h2d)
-        return _gather(hj, jnp.asarray(a), tile=t, interpret=interpret)[:n]
+        launched = _gather(hj, jnp.asarray(a), tile=t,
+                           interpret=interpret)
+    vals, block = jax.device_get(launched)
+    COUNTS.moved("gather_read", d2h=vals.nbytes + block.nbytes)
+    COUNTS.tiled(block=int(block), row=a.shape[0] // t - int(block))
+    return vals[:n]
 
 
 @functools.partial(jax.jit, donate_argnums=(0,),

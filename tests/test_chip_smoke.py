@@ -137,6 +137,9 @@ def test_tm_phase_tiny(kernel_branch):
     # words come back to the host
     assert out["h2d_bytes"]["heap_upload"] > 0
     assert out["d2h_bytes"]["gather_read"] > 0
+    # every launch's grid steps are counted by the path they took
+    assert (sum(out["gather_tiles"].values())
+            >= out["site_entries"]["gather_read"])
     scan = out["scan"]
     assert scan["bulk_batch_words"] > 0
     assert min(scan["version_gather_hits"], scan["bulk_scalar_words"]) >= 0

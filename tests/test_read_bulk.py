@@ -187,6 +187,48 @@ def test_ops_snapshot_read_pads_ragged_batches():
         np.testing.assert_array_equal(out, np.arange(1000)[addrs])
 
 
+_SCATTER = np.random.default_rng(3).integers(0, 5000, 1024)
+
+#: name -> (heap words, addresses, block steps, row steps); every batch
+#: is gathered in 1024-address steps
+_PATH_CASES = {
+    "row_aligned_run": (7048, np.arange(1024, 3072), 2, 0),
+    "unaligned_run": (7048, np.arange(37, 2085), 2, 0),
+    # 5000 + n words: the run ends in the last row, which is partial
+    "run_to_ragged_heap_end": (7048, np.arange(5000, 7048), 2, 0),
+    # an aligned run up to the last row of a whole-row heap
+    "aligned_run_to_heap_end": (4096, np.arange(2048, 4096), 2, 0),
+    # the second step holds 76 run words and 948 PAD_ADDR pads
+    "run_then_padding": (7048, np.arange(300, 1400), 1, 1),
+    "run_then_scattered": (7048, np.concatenate(
+        [np.arange(4000, 5024), _SCATTER]), 1, 1),
+    "run_turns_scattered_mid_step": (7048, np.concatenate(
+        [np.arange(10, 1510), _SCATTER[:548]]), 1, 1),
+    "stride_2": (7048, np.arange(0, 4096, 2), 0, 2),
+    "descending": (7048, np.arange(3000, 952, -1), 0, 2),
+    "repeated": (7048, np.full(1024, 777), 0, 1),
+    "one_step_run": (7048, np.arange(129, 1153), 1, 0),
+    "one_address": (7048, np.array([4321]), 0, 1),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_PATH_CASES))
+def test_snapshot_read_block_and_row_paths(case):
+    """A step of consecutive addresses takes the one-copy block path,
+    any other step the per-address row path; both equal ``heap[a]``,
+    and ``COUNTS.tiles`` counts the steps by the path they took."""
+    from repro.kernels import ops
+    words, a, block, row = _PATH_CASES[case]
+    heap = np.random.default_rng(words).integers(
+        -(1 << 30), 1 << 30, words).astype(np.int32)
+    ops.COUNTS.reset()
+    out = ops.snapshot_read(heap, a, interpret=True)
+    np.testing.assert_array_equal(out, heap[a])
+    assert ops.COUNTS.tiles == {"gather_block_tiles": block,
+                                "gather_row_tiles": row}
+    ops.COUNTS.reset()
+
+
 # ---------------------------------------------------------------------------
 # concurrency: balance-preserving snapshots (the satellite)
 # ---------------------------------------------------------------------------

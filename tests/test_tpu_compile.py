@@ -24,6 +24,7 @@ CHUNK = 1 << 16         # its read_bulk chunk
 WRITES = 4096           # its kernels-phase write batch
 ROWS = 4096             # its validate / version_select batch
 RING = 1 << 20          # its snapshot_select ring row
+BLOCK = 1 << 26         # a whole-block read_bulk of a 2^26-word store block
 
 
 @pytest.fixture(scope="module")
@@ -66,6 +67,11 @@ def _programs(one_chip):
         "gather_read_ragged_heap": (
             lambda h, a: ops._gather(h, a, tile=1024, interpret=False),
             [s(HEAP + 3), s(CHUNK)]),
+        # 65,536 grid steps: the per-step path flags reach SMEM a block
+        # at a time, so the batch length is bounded by HBM alone
+        "gather_read_whole_block": (
+            lambda h, a: ops._gather(h, a, tile=1024, interpret=False),
+            [s(BLOCK), s(BLOCK)]),
         "scatter_write": (
             lambda r, a, v: ops._scatter(r, a, v, tile=wt,
                                          interpret=False),
@@ -92,7 +98,8 @@ def _programs(one_chip):
     }
 
 
-KERNELS = ("gather_read", "gather_read_ragged_heap", "scatter_write",
+KERNELS = ("gather_read", "gather_read_ragged_heap",
+           "gather_read_whole_block", "scatter_write",
            "validate", "version_select", "snapshot_select",
            "snapshot_select_bf16", "commit_fused")
 
